@@ -25,13 +25,6 @@ from ..utils.jaxcache import ensure_compile_cache
 ensure_compile_cache()
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax.shard_map graduated from jax.experimental in newer releases;
-# resolve whichever this jax ships
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..scan import zscan
 
 __all__ = ["data_mesh", "DistributedScanData", "shard_scan_data",
@@ -117,7 +110,7 @@ _SPECS_IN = (P("data"), P("data"), P("data"), P("data"),
 
 @functools.lru_cache(maxsize=32)
 def _mask_fn(mesh: Mesh, time_any: bool):
-    return jax.jit(_shard_map(_shard_mask_fn(time_any), mesh=mesh,
+    return jax.jit(jax.shard_map(_shard_mask_fn(time_any), mesh=mesh,
                                  in_specs=_SPECS_IN, out_specs=P("data")))
 
 
@@ -129,7 +122,7 @@ def _count_fn(mesh: Mesh, time_any: bool):
         mask = body(*args)
         return jax.lax.psum(jnp.sum(mask, dtype=jnp.int32), "data")
 
-    return jax.jit(_shard_map(counted, mesh=mesh,
+    return jax.jit(jax.shard_map(counted, mesh=mesh,
                                  in_specs=_SPECS_IN, out_specs=P()))
 
 
@@ -243,7 +236,7 @@ def _shard_batch_mask_fn():
 
 @functools.lru_cache(maxsize=32)
 def _batch_mask_fn(mesh: Mesh):
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         _shard_batch_mask_fn(), mesh=mesh, in_specs=_SPECS_IN,
         out_specs=(P(None, "data"), P(None, "data"))))
 
@@ -315,7 +308,7 @@ def _density_fn(mesh: Mesh, time_any: bool,
         grid = grid.at[flat].add(mask.astype(jnp.float32))
         return jax.lax.psum(grid, "data")
 
-    return jax.jit(_shard_map(density, mesh=mesh,
+    return jax.jit(jax.shard_map(density, mesh=mesh,
                                  in_specs=_SPECS_IN, out_specs=P()))
 
 
@@ -332,7 +325,7 @@ def _hist_fn(mesh: Mesh, nbins: int, lo: float, hi: float):
         h = h.at[b].add(mask.astype(jnp.int32))
         return jax.lax.psum(h, "data")
 
-    return jax.jit(_shard_map(body, mesh=mesh,
+    return jax.jit(jax.shard_map(body, mesh=mesh,
                                  in_specs=(P("data"), P("data")),
                                  out_specs=P()))
 
@@ -358,7 +351,7 @@ def _minmax_fn(mesh: Mesh):
         vmax = jnp.max(jnp.where(mask, values, jnp.float32(-np.inf)))
         return (jax.lax.pmin(vmin, "data"), jax.lax.pmax(vmax, "data"))
 
-    return jax.jit(_shard_map(body, mesh=mesh,
+    return jax.jit(jax.shard_map(body, mesh=mesh,
                                  in_specs=(P("data"), P("data")),
                                  out_specs=(P(), P())))
 
@@ -440,7 +433,7 @@ def _tristate_fn(mesh: Mesh, time_any: bool, has_time: bool):
                                     tday, tms, outer, inner, bvalid,
                                     times, tvalid, time_any, has_time)
 
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("data"),) * 7 + (P(),) * 5,
         out_specs=P("data")))
 
@@ -487,7 +480,7 @@ def _contains_fn(mesh: Mesh, band_cap: int):
         dc, bc, brows = jax.lax.map(one, (boxes, edges, evalid))
         return jax.lax.psum(dc, "data"), bc, brows
 
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("data"), P("data"), P(), P(), P()),
         out_specs=(P(), P(None, "data"), P(None, "data"))))

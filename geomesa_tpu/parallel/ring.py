@@ -34,13 +34,6 @@ ensure_compile_cache()
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax.shard_map graduated from jax.experimental in newer releases;
-# resolve whichever this jax ships
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 __all__ = ["ring_dwithin_counts", "distributed_knn", "shard_points",
            "shard_points_split"]
 
@@ -92,15 +85,8 @@ def _ring_dwithin_fn(mesh: Mesh, r_in2: float, r_out2: float):
 
         # the carry must be marked device-varying over the mesh axis to
         # match the loop outputs under shard_map
-        zeros = jnp.zeros(lx.shape, jnp.int32)
-        pcast = getattr(lax, "pcast", None)
-        pvary = getattr(lax, "pvary", None)
-        if pcast is not None:
-            zeros = pcast(zeros, "data", to="varying")
-        elif pvary is not None:
-            zeros = pvary(zeros, ("data",))
-        # else: jax predates varying-ness tracking; shard_map accepts
-        # the replicated carry as-is
+        zeros = lax.pcast(jnp.zeros(lx.shape, jnp.int32), "data",
+                          to="varying")
         # k-1 [compute, rotate] steps, then the final block without the
         # rotation (its permuted output would be discarded)
         rx, ry, rvalid, sure, band = lax.fori_loop(
@@ -109,7 +95,7 @@ def _ring_dwithin_fn(mesh: Mesh, r_in2: float, r_out2: float):
         return jnp.where(lvalid, sure, 0), jnp.where(lvalid, band, 0)
 
     specs = (P("data"),) * 6
-    return jax.jit(_shard_map(body, mesh=mesh, in_specs=specs,
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=specs,
                                  out_specs=(P("data"), P("data"))))
 
 
@@ -175,7 +161,7 @@ def _knn_prune_split_fn(mesh: Mesh, k: int):
         take = lambda a: jnp.take(a, idx)
         return (-neg_top, gids, take(xhi), take(xlo), take(yhi), take(ylo))
 
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("data"),) * 5 + (P(),),
         out_specs=(P("data"),) * 6))
@@ -194,7 +180,7 @@ def _knn_prune_fn(mesh: Mesh, k: int):
         # sharded outputs gather host-side (tiny transfer)
         return -neg_top, gids
 
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("data"), P("data"), P("data"), P()),
         out_specs=(P("data"), P("data"))))
 

@@ -435,6 +435,9 @@ class QueryBatcher:
                     runtime.note_dispatch("batcher", shape, dt)
             except Exception as e:  # noqa: BLE001
                 dsp.annotate("dispatch.failed", error=str(e))
+                # the per-query replay below hides the failure from
+                # callers; the counter keeps it visible
+                self.registry.counter("batcher.dispatch.failed")
                 err = e
         # graft BEFORE resolving: the dispatch subtree lands in every
         # follower's trace while their roots are still open
@@ -504,6 +507,9 @@ class QueryBatcher:
                         h2d_bytes=int(qx.nbytes + qy.nbytes))
             except Exception as e:  # noqa: BLE001
                 dsp.annotate("dispatch.failed", error=str(e))
+                # the per-query replay below hides the failure from
+                # callers; the counter keeps it visible
+                self.registry.counter("batcher.dispatch.failed")
                 err = e
         tracer.graft(dsp, [p.span_ctx for p in chunk])
         if err is None:

@@ -10,9 +10,13 @@ taken all the way down to the kernel level.
 
 Layout: columns are padded and reshaped to (rows, 128) f32/i32 tiles;
 the grid walks row blocks of BLOCK_R x 128 (double-buffered HBM->VMEM
-streaming is implicit in the BlockSpec pipeline). Query boxes/times are
-small VMEM-resident tables; invalid padding slots carry impossible
-bounds so the kernel needs no validity masks.
+streaming is implicit in the BlockSpec pipeline). Inside a block the
+kernel loops over SUB_R-row slices, so its temporaries (one bool tile
+per compare, per box) stay a fixed fraction of the 16 MiB scoped VMEM
+for any box count: evaluated over the whole block they overflowed it
+for K >= 4 on a v5e. Query boxes/times are small VMEM-resident tables;
+invalid padding slots carry impossible bounds so the kernel needs no
+validity masks.
 
 Numerics are identical to zscan: two-float lexicographic compares for
 space, (day, ms) int32 pairs for time — so `pallas_scan_mask` is
@@ -30,25 +34,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..utils.jaxcache import ensure_compile_cache
-
-ensure_compile_cache()
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from ..utils.jaxcache import ensure_compile_cache
 from .zscan import MILLIS_PER_DAY, ScanQuery, split_two_float
 
-try:  # TPU-only module; absent on CPU-only installs of pallas
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+ensure_compile_cache()
 
 __all__ = ["PallasScanData", "build_pallas_data", "pallas_scan_mask",
            "pallas_scan_count", "pallas_query_tables", "BLOCK_R"]
 
 LANES = 128
-BLOCK_R = 2048  # rows per grid step
+BLOCK_R = 1024  # rows per grid step: six 512 KiB input tiles, double-buffered
+SUB_R = 256     # rows per in-kernel slice; a multiple of the int8 tile (32)
 
 
 def _interpret() -> bool:
@@ -136,23 +135,41 @@ def _block_mask(xhi, xlo, yhi, ylo, tday, tms, boxes_ref, times_ref,
     return m
 
 
+def _col_specs():
+    col = pl.BlockSpec((BLOCK_R, LANES), lambda i: (i, 0),
+                       memory_space=pltpu.VMEM)
+    small = pl.BlockSpec(memory_space=pltpu.VMEM)
+    return [small, small] + [col] * 6, col
+
+
+def _slice_mask(refs, r0, boxes_ref, times_ref, k, b, time_any):
+    """Mask of rows [r0, r0 + SUB_R) of the current block."""
+    sl = pl.ds(pl.multiple_of(r0, SUB_R), SUB_R)
+    return _block_mask(*(r[sl, :] for r in refs), boxes_ref, times_ref,
+                       k, b, time_any)
+
+
 @functools.partial(jax.jit, static_argnames=("k", "b", "time_any", "rows"))
 def _mask_call(xhi, xlo, yhi, ylo, tday, tms, boxes, times,
                k: int, b: int, time_any: bool, rows: int):
-    def kernel(boxes_ref, times_ref, xh, xl, yh, yl, td, tm, out_ref):
-        out_ref[:] = _block_mask(xh[:], xl[:], yh[:], yl[:], td[:], tm[:],
-                                 boxes_ref, times_ref, k, b,
-                                 time_any).astype(jnp.int8)
+    def kernel(boxes_ref, times_ref, *refs):
+        *cols, out_ref = refs
 
-    grid = (rows // BLOCK_R,)
-    col = pl.BlockSpec((BLOCK_R, LANES), lambda i: (i, 0),
-                       memory_space=_VMEM)
-    small = pl.BlockSpec(memory_space=_VMEM)
+        def body(s, carry):
+            r0 = s * SUB_R
+            m = _slice_mask(cols, r0, boxes_ref, times_ref, k, b, time_any)
+            out_ref[pl.ds(pl.multiple_of(r0, SUB_R), SUB_R), :] = \
+                m.astype(jnp.int8)
+            return carry
+
+        jax.lax.fori_loop(0, BLOCK_R // SUB_R, body, 0)
+
+    in_specs, col = _col_specs()
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int8),
-        grid=grid,
-        in_specs=[small, small] + [col] * 6,
+        grid=(rows // BLOCK_R,),
+        in_specs=in_specs,
         out_specs=col,
         interpret=_interpret(),
     )(boxes, times, xhi, xlo, yhi, ylo, tday, tms)
@@ -161,10 +178,16 @@ def _mask_call(xhi, xlo, yhi, ylo, tday, tms, boxes, times,
 @functools.partial(jax.jit, static_argnames=("k", "b", "time_any", "rows"))
 def _count_call(xhi, xlo, yhi, ylo, tday, tms, boxes, times,
                 k: int, b: int, time_any: bool, rows: int):
-    def kernel(boxes_ref, times_ref, xh, xl, yh, yl, td, tm, out_ref):
-        m = _block_mask(xh[:], xl[:], yh[:], yl[:], td[:], tm[:],
-                        boxes_ref, times_ref, k, b, time_any)
-        partial = jnp.sum(m, dtype=jnp.int32)
+    def kernel(boxes_ref, times_ref, *refs):
+        *cols, out_ref = refs
+
+        def body(s, acc):
+            m = _slice_mask(cols, s * SUB_R, boxes_ref, times_ref, k, b,
+                            time_any)
+            return acc + jnp.sum(m, dtype=jnp.int32)
+
+        partial = jax.lax.fori_loop(0, BLOCK_R // SUB_R, body,
+                                    jnp.int32(0))
 
         @pl.when(pl.program_id(0) == 0)
         def _init():
@@ -172,19 +195,16 @@ def _count_call(xhi, xlo, yhi, ylo, tday, tms, boxes, times,
 
         out_ref[0, 0] += partial
 
-    grid = (rows // BLOCK_R,)
-    col = pl.BlockSpec((BLOCK_R, LANES), lambda i: (i, 0),
-                       memory_space=_VMEM)
-    small = pl.BlockSpec(memory_space=_VMEM)
+    in_specs, _ = _col_specs()
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        grid=grid,
-        in_specs=[small, small] + [col] * 6,
+        grid=(rows // BLOCK_R,),
+        in_specs=in_specs,
         # every grid step maps to the same output block -> sequential
         # accumulation across steps; SMEM because the store is scalar
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0),
-                               memory_space=(pltpu.SMEM if pltpu else None)),
+                               memory_space=pltpu.SMEM),
         interpret=_interpret(),
     )(boxes, times, xhi, xlo, yhi, ylo, tday, tms)
 

@@ -122,6 +122,13 @@ class DistributedDataStore(InMemoryDataStore):
     def _new_state(self, sft: SimpleFeatureType) -> _MeshTypeState:
         return _MeshTypeState(sft, self.mesh)
 
+    @staticmethod
+    def _prewarm_join(st):
+        """No ingest-time join prewarm: the single-device join kernels it
+        compiles would take an unsharded copy of every coordinate onto
+        the mesh's first device, while this store's device tier is the
+        sharded segments (KNN runs as distributed_knn)."""
+
     # -- scan tiers over the sharded segments ------------------------------
 
     def _scan_gathered(self, st: _MeshTypeState, sq: zscan.ScanQuery,
@@ -283,7 +290,6 @@ class DistributedDataStore(InMemoryDataStore):
         """Distributed attribute histogram: shard-local bincount merged
         over ICI with psum (StatsCombiner merge analog)."""
         import jax
-        import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         st = self._state(type_name)
@@ -298,9 +304,11 @@ class DistributedDataStore(InMemoryDataStore):
         vp[: st.n] = v
         m = np.zeros(n_padded, dtype=bool)
         m[: st.n] = np.asarray(vals.valid)
+        # host arrays straight to the sharding: a jnp.asarray first would
+        # build the whole column on the default device (chip 0)
         sh = NamedSharding(self.mesh, P("data"))
-        return distributed_histogram(jax.device_put(jnp.asarray(vp), sh),
-                                     jax.device_put(jnp.asarray(m), sh),
+        return distributed_histogram(jax.device_put(vp, sh),
+                                     jax.device_put(m, sh),
                                      self.mesh, nbins, lo, hi)
 
     def _arrow_ipc_uncached(self, type_name: str, ecql="INCLUDE",

@@ -3,15 +3,14 @@
 The reference ships its server-side code as a pre-built jar to the
 tablet servers (geomesa-accumulo-distributed-runtime), so scan
 machinery never compiles at query time. The TPU analog: persist XLA
-executables across processes so only the FIRST process ever pays the
-20-40s trace+compile of the scan/join kernels — every later run (and
-every benchmark round) loads them from disk.
+executables across processes so only the FIRST process pays the
+trace+compile of the scan/join kernels.
 
-Enabled the first time any kernel module imports; configuration:
-
-- ``GEOMESA_TPU_COMPILE_CACHE`` — cache directory (default:
-  ``<repo>/.jax_cache``)
-- ``GEOMESA_TPU_NO_COMPILE_CACHE=1`` — disable entirely
+Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it and this module
+sets no directory. Otherwise the cache lives at ``<checkout>/.jax_cache``:
+a fixed path, because the path is part of the cache's key and a directory
+that moves never hits. ``JAX_ENABLE_COMPILATION_CACHE=false`` turns the
+cache off.
 """
 
 from __future__ import annotations
@@ -19,48 +18,17 @@ from __future__ import annotations
 import os
 import pathlib
 
-_done = False
+import jax
+
+CACHE_DIR = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
 def ensure_compile_cache() -> None:
-    """Idempotent: point JAX at the persistent compilation cache."""
-    global _done
-    if _done or os.environ.get("GEOMESA_TPU_NO_COMPILE_CACHE"):
-        _done = True
-        return
-    _done = True
-    try:
-        import jax
-
-        d = os.environ.get("GEOMESA_TPU_COMPILE_CACHE")
-        candidates = ([d] if d else
-                      [str(pathlib.Path(__file__).resolve().parents[2]
-                           / ".jax_cache"),
-                       # read-only installs (site-packages): user cache
-                       os.path.join(os.path.expanduser("~"), ".cache",
-                                    "geomesa_tpu", "jax")])
-        d = None
-        for cand in candidates:
-            try:
-                pathlib.Path(cand).mkdir(parents=True, exist_ok=True)
-                probe = pathlib.Path(cand) / ".wtest"
-                probe.touch()
-                probe.unlink()
-                d = cand
-                break
-            except OSError:
-                continue
-        if d is None:
-            return
-        jax.config.update("jax_compilation_cache_dir", d)
-        # cache everything that took meaningful compile time; the
-        # default threshold skips exactly the 1-2s kernels that add up
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.5)
-        try:
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception:
-            pass  # knob absent on older jax
-    except Exception:
-        pass  # cache is an optimization, never a failure mode
+    """Point JAX at the persistent compilation cache (called when each
+    kernel module is imported; repeated calls set the same values)."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    # cache everything that took meaningful compile time; the default
+    # threshold skips exactly the 1-2s kernels that add up
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)

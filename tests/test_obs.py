@@ -492,7 +492,11 @@ class TestBatcherFanIn:
     def test_coalesced_followers_get_dispatch_subtree(self, sampled):
         from geomesa_tpu.scan.batcher import QueryBatcher
         ds = seeded_store(cls=_GatedStore)
-        b = QueryBatcher(ds, max_batch=2, linger_us=5_000_000)
+        # static linger: an adaptive one sizes the wait from the gap
+        # before the first follower, and a loaded host can then dispatch
+        # it alone before the second arrives
+        b = QueryBatcher(ds, max_batch=2, linger_us=5_000_000,
+                         adaptive=False)
         # gate a sacrificial dispatch in flight: the leader only lingers
         # for followers under load, so this makes coalescing
         # deterministic instead of a thread race
