@@ -1,0 +1,11 @@
+"""The benchmark's own tests run on the CPU: ``JAX_PLATFORMS=cpu`` is set
+before JAX is imported, and the scan tiers' host cap is lowered (a program
+property, ``geomesa.scan.host.rows``) so a table of a few hundred thousand
+rows still reaches the gathered and dense device tiers."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["GEOMESA_SCAN_HOST_ROWS"] = "2000"
+sys.path[:0] = [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
