@@ -110,7 +110,7 @@ def audit_query(audit: AuditLogger | None, surface: str,
         trace_id=current_trace_id(), surface=surface, index=index,
         rows_scanned=rows_scanned,
         cache_hit=bool(get_flag("cache_hit", False)),
-        batched=batched or bool(get_flag("batched", False)),
+        batched=batched,
         hedged=bool(get_flag("hedged", False)),
         tenant=active_tenant())
     return True

@@ -6,13 +6,17 @@ the join prewarm) whose MISSES predict XLA retraces — the single
 biggest latency cliff on an accelerator tier. This collector is the
 one place those caches report to: per-domain, per-shape-class
 compile-vs-hit counts, fused-dispatch wall timers, host<->device
-transfer bytes, and sampled device memory (current, high-water mark,
-live buffer count/bytes).
+transfer bytes, sampled device memory (current, high-water mark,
+live buffer count/bytes), and the backend compiles that actually ran
+(one ``jax.monitoring`` listener, which also notes each compile on the
+current trace span, so a trace shows which step of which request
+recompiled).
 
 Everything lands twice: in the labeled metrics registry
 (``runtime.compile{domain,class,outcome}`` counters,
-``runtime.dispatch{domain,class}`` timers, ``runtime.device.bytes``
-gauges, ``runtime.h2d.bytes``/``runtime.d2h.bytes`` counters) for
+``runtime.compile.backend`` counter, ``runtime.dispatch{domain,class}``
+timers, ``runtime.device.bytes`` gauges,
+``runtime.h2d.bytes``/``runtime.d2h.bytes`` counters) for
 scraping, and in an internal table the ``GET /rest/runtime`` snapshot
 serves directly.
 
@@ -40,6 +44,9 @@ __all__ = ["RuntimeCollector", "runtime", "RUNTIME_ENABLED"]
 
 RUNTIME_ENABLED = SystemProperty("geomesa.runtime.enabled", "true")
 
+# fires for every XLA backend compile, a persistent-cache load included
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
 
 def _cls(shape) -> str:
     """A shape class (tuple of type/version/pow2 caps, or anything
@@ -65,6 +72,8 @@ class RuntimeCollector:
         self._live_bytes_hwm = 0
         self._mem_samples = 0
         self._mem_sampled_at: float | None = None
+        self._backend_compiles = [0, 0.0]   # count, seconds
+        self._watching = False
 
     @staticmethod
     def enabled() -> bool:
@@ -105,6 +114,31 @@ class RuntimeCollector:
             self._registry.counter("runtime.h2d.bytes", int(h2d_bytes))
         if d2h_bytes:
             self._registry.counter("runtime.d2h.bytes", int(d2h_bytes))
+
+    # -- backend compiles --------------------------------------------------
+
+    def watch_compiles(self):
+        """Register the backend-compile listener once; a no-op until jax
+        is loaded (called as each kernel module sets up its cache)."""
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return
+        with self._lock:
+            if self._watching:
+                return
+            self._watching = True
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_compile)
+
+    def _on_compile(self, event: str, seconds: float, **_):
+        if event != COMPILE_EVENT or not self.enabled():
+            return
+        with self._lock:
+            self._backend_compiles[0] += 1
+            self._backend_compiles[1] += seconds
+        self._registry.counter("runtime.compile.backend")
+        from .trace import annotate
+        annotate("compile", seconds=round(seconds, 6))
 
     # -- device memory -----------------------------------------------------
 
@@ -181,6 +215,9 @@ class RuntimeCollector:
             return {
                 "enabled": self.enabled(),
                 "compile": compiles,
+                "backend_compile": {
+                    "count": self._backend_compiles[0],
+                    "total_ms": round(self._backend_compiles[1] * 1e3, 3)},
                 "dispatch": dispatches,
                 "transfer": {"h2d_bytes": self._h2d_bytes,
                              "d2h_bytes": self._d2h_bytes},
@@ -204,6 +241,7 @@ class RuntimeCollector:
             self._live_bytes_hwm = 0
             self._mem_samples = 0
             self._mem_sampled_at = None
+            self._backend_compiles = [0, 0.0]
 
 
 runtime = RuntimeCollector()

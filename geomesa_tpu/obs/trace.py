@@ -31,6 +31,14 @@ the ingest group commit covering N staged batches) record **links** to
 the waiting callers' spans; ``Tracer.graft`` additionally clones the
 dispatch subtree into each follower's trace so a follower's slow-query
 capture still shows where its time went.
+
+Every live span is also a ``jax.profiler.TraceAnnotation`` named
+``geomesa.<kind>``, so a profiler capture shows each step beside the
+device ops on one clock (the ring's ``start_ms`` is wall-clock and lines
+up with nothing in the capture). The annotation closes only on the thread
+that entered the span, and only once ``jax`` is already loaded: tracing
+never imports it. Outside a capture an annotation costs well under a
+microsecond.
 """
 
 from __future__ import annotations
@@ -38,6 +46,7 @@ from __future__ import annotations
 import contextvars
 import json
 import random
+import sys
 import threading
 import time
 from collections import OrderedDict
@@ -70,14 +79,13 @@ class _TraceState:
     so the audit hook can read them without plumbing arguments through
     every tier."""
 
-    __slots__ = ("trace_id", "sampled", "spans", "flags", "start_ms")
+    __slots__ = ("trace_id", "sampled", "spans", "flags")
 
     def __init__(self, trace_id: str, sampled: bool):
         self.trace_id = trace_id
         self.sampled = sampled
         self.spans: list[Span] = []
         self.flags: dict = {}
-        self.start_ms = int(time.time() * 1000)
 
 
 # (state, current span) — None outside any trace
@@ -92,7 +100,8 @@ class Span:
 
     __slots__ = ("trace_id", "span_id", "parent_id", "kind", "name",
                  "start_ms", "duration_ms", "attrs", "annotations",
-                 "links", "error", "_t0", "_state", "_token", "_root")
+                 "links", "error", "_t0", "_state", "_token", "_root",
+                 "_ann", "_tid")
 
     def __init__(self, state: _TraceState, kind: str, name: str,
                  parent_id: str | None, root: bool):
@@ -111,6 +120,8 @@ class Span:
         self._state = state
         self._token = None
         self._root = root
+        self._ann = None
+        self._tid = None
 
     # -- enrichment -------------------------------------------------
     def annotate(self, text: str, **attrs):
@@ -126,15 +137,11 @@ class Span:
     def link(self, trace_id: str, span_id: str):
         self.links.append({"trace_id": trace_id, "span_id": span_id})
 
-    def set_flag(self, name: str, value=True):
-        """Set a trace-level flag (read by the audit hook) directly on
-        this span's trace — usable from callback threads that do not
-        carry the caller's contextvars."""
-        self._state.flags[name] = value
-
     # -- context protocol -------------------------------------------
     def __enter__(self):
         self._token = _CTX.set((self._state, self))
+        self._ann = _annotation(self.kind)
+        self._tid = threading.get_ident()
         return self
 
     def __exit__(self, exc_type, exc, tb):
@@ -156,6 +163,12 @@ class Span:
         if self.duration_ms == 0.0:
             self.duration_ms = round(
                 (time.perf_counter() - self._t0) * 1000, 3)
+        # the annotation belongs to the entering thread's timeline; a span
+        # finished on another thread leaves it unclosed (jaxlib cannot
+        # discard an open annotation: it ends where it is released)
+        ann, self._ann = self._ann, None
+        if ann is not None and threading.get_ident() == self._tid:
+            ann.__exit__(None, None, None)
         self._state.spans.append(self)
         if self._root:
             tracer._finalize(self._state, self)
@@ -193,7 +206,19 @@ class Span:
         c._state = state
         c._token = None
         c._root = False
+        c._ann = c._tid = None
         return c
+
+
+def _annotation(kind: str):
+    """The profiler event of a live span, entered; None while ``jax`` is
+    not loaded (telemetry never imports it, as in obs/runtime.py)."""
+    prof = getattr(sys.modules.get("jax"), "profiler", None)
+    if prof is None:
+        return None
+    ann = prof.TraceAnnotation(f"geomesa.{kind}")
+    ann.__enter__()
+    return ann
 
 
 class _NullSpan:
@@ -217,9 +242,6 @@ class _NullSpan:
         pass
 
     def link(self, trace_id, span_id):
-        pass
-
-    def set_flag(self, name, value=True):
         pass
 
     def finish(self):

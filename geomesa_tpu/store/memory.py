@@ -22,6 +22,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import threading
+import time
 import weakref
 from typing import Any, Iterator
 
@@ -36,6 +37,7 @@ from ..filters.helper import extract_geometries, extract_intervals
 from ..geometry import Envelope
 from ..index.api import Explainer, FilterStrategy, Query, QueryHints
 from ..index.planner import decide_strategy
+from ..obs import runtime, tracer
 from .api import DataStore
 from ..scan import gscan, zscan
 from ..stats import DataStoreStats, parse_stat
@@ -156,11 +158,14 @@ class QueryResult:
     """
 
     def __init__(self, ids, batch, explain: Explainer,
-                 plan: FilterStrategy, n: int | None = None):
+                 plan: FilterStrategy, n: int | None = None,
+                 scan_span: tuple | None = None):
         # ids may be a thunk: the object-array id gather at 10M+ rows
         # costs more than many whole queries, and join/count consumers
-        # never read it
+        # never read it. scan_span is the query's store-scan span as
+        # (trace_id, span_id): the deferred gather's span links to it
         self._ids = ids
+        self._scan_span = scan_span
         self._n = n if n is not None else len(ids)
         self._batch = batch          # FeatureBatch | None | _LazyBatch
         self.explain = explain
@@ -169,7 +174,12 @@ class QueryResult:
     @property
     def ids(self) -> np.ndarray:
         if callable(self._ids):
-            self._ids = self._ids()
+            # runs after the request's trace has closed: a root of its own
+            with tracer.span("result-ids", root=True) as sp:
+                if self._scan_span is not None:
+                    sp.link(*self._scan_span)
+                self._ids = self._ids()
+                sp.set_attr(ids=len(self._ids))
         return self._ids
 
     @property
@@ -1101,9 +1111,10 @@ class InMemoryDataStore(DataStore):
 
         import time as _time
         try:
-            t_plan0 = _time.perf_counter()
-            strategy, art = self._plan_for(q, st, explain)
-            t_plan = _time.perf_counter() - t_plan0
+            with tracer.span("plan"):
+                t_plan0 = _time.perf_counter()
+                strategy, art = self._plan_for(q, st, explain)
+                t_plan = _time.perf_counter() - t_plan0
             if managed is not None:
                 managed.check()
             t_scan0 = _time.perf_counter()
@@ -1200,7 +1211,6 @@ class InMemoryDataStore(DataStore):
             explain("Store is empty").pop()
             return QueryResult(np.empty(0, dtype=object), None, explain,
                                FilterStrategy("empty", None, None))
-        from ..obs import tracer
         with tracer.span("store-scan", q.type_name) as sp:
             idx, strategy, t_plan, t_scan0, attr_mask = \
                 self._matching_rows(q, st, explain)
@@ -1215,89 +1225,95 @@ class InMemoryDataStore(DataStore):
                       t_scan0: float, batched: bool = False) -> QueryResult:
         """Result-assembly stages shared by the scalar and batched
         pipelines: sort, max_features, projection validation, lazy
-        batch + attribute-cell redaction, id gather, audit."""
-        import time as _time
-        if q.sort_by is not None:
-            from .common import sort_order
-            hidden = None
-            if attr_mask is not None:
-                # hidden sort values must not leak through the row
-                # ordering: they sort as NULL
-                aj = {a.name: j
-                      for j, a in enumerate(st.sft.attributes)}.get(q.sort_by)
-                if aj is not None:
-                    hidden = ~attr_mask[:, aj]
-            order = sort_order(st.batch, q.sort_by, q.sort_desc, idx,
-                               hidden=hidden)
-            idx = idx[order]
-            if attr_mask is not None:
-                attr_mask = attr_mask[order]
-        if q.max_features is not None:
-            idx = idx[:q.max_features]
-            if attr_mask is not None:
-                attr_mask = attr_mask[:q.max_features]
+        batch + attribute-cell redaction, id gather, audit. Runs inside
+        the query's store-scan span, which the result keeps for the
+        deferred id gather's link."""
+        cur = tracer.current()
+        scan_span = (cur[0].trace_id, cur[1].span_id) if cur else None
+        with tracer.span("assemble") as sp:
+            if q.sort_by is not None:
+                from .common import sort_order
+                hidden = None
+                if attr_mask is not None:
+                    # hidden sort values must not leak through the row
+                    # ordering: they sort as NULL
+                    aj = {a.name: j for j, a
+                          in enumerate(st.sft.attributes)}.get(q.sort_by)
+                    if aj is not None:
+                        hidden = ~attr_mask[:, aj]
+                order = sort_order(st.batch, q.sort_by, q.sort_desc, idx,
+                                   hidden=hidden)
+                idx = idx[order]
+                if attr_mask is not None:
+                    attr_mask = attr_mask[order]
+            if q.max_features is not None:
+                idx = idx[:q.max_features]
+                if attr_mask is not None:
+                    attr_mask = attr_mask[:q.max_features]
 
-        if q.properties is not None:
-            # validate projection names NOW: errors belong to query(),
-            # not to whenever (or whether) .batch is first read
-            missing = [p for p in q.properties
-                       if p not in st.batch.columns]
-            if missing:
-                raise KeyError(f"unknown propert"
-                               f"{'ies' if len(missing) > 1 else 'y'}: "
-                               f"{', '.join(missing)}")
-        batch: Any = _LazyBatch(st.batch, idx, q.properties,
-                                row_order=q.sort_by is None)
-        st.live_lazy.add(batch)
-        if attr_mask is not None:
-            # null unauthorized attribute values in the result rows
-            # (KryoVisibilityRowEncoder: the row is assembled from the
-            # cells the scanner's auths can see)
-            m = attr_mask
-            if not m.all():
-                mb = batch.materialize() if isinstance(batch, _LazyBatch) \
-                    else batch
-                by_name = {a.name: j
-                           for j, a in enumerate(st.sft.attributes)}
-                cols = {}
-                for a in mb.sft.attributes:
-                    col = mb.col(a.name)
-                    bad = ~m[:, by_name[a.name]]
-                    cols[a.name] = (_null_cells(col, bad) if bad.any()
-                                    else col)
-                batch = FeatureBatch(mb.sft, mb.ids, cols)
-        if isinstance(batch, FeatureBatch):
-            # attr-visibility path materialized already; reuse its ids
-            ids = batch.ids
-        elif len(idx) <= 100_000:
-            # eager id gather (the result's identity), lazy columns:
-            # id-only consumers — count checks, bench loops, join sides
-            # — never pay the per-column copies, and .batch still
-            # materializes on first read (the reference's readers are
-            # lazy over their scan buffers the same way,
-            # KryoBufferSimpleFeature). The result pins the immutable
-            # column snapshot until dropped.
-            ids = st.batch.ids[idx]
-        else:
-            # deferred gather against the immutable batch snapshot:
-            # large results are often consumed via batch columns (or
-            # only counted) and never read ids at all
-            src = st.batch
-            ids = (lambda: src.ids[idx])
-        explain(f"Hits: {len(idx)}").pop()
-        scan_s = _time.perf_counter() - t_scan0
-        from ..metrics import metrics as _metrics
-        _metrics.observe("store.scan", scan_s,
-                         labels={"type": q.type_name,
-                                 "index": strategy.index or "none"})
-        from ..obs.slo import slo_engine
-        slo_engine.record("store.scan", ok=True, latency_s=scan_s)
-        from ..audit import audit_query
-        audit_query(self.audit, "memory", q.type_name, str(q.filter),
-                    q.hints, t_plan * 1000, scan_s * 1000, len(idx),
-                    index=strategy.index, rows_scanned=int(st.n),
-                    batched=batched)
-        return QueryResult(ids, batch, explain, strategy, n=len(idx))
+            if q.properties is not None:
+                # validate projection names NOW: errors belong to query(),
+                # not to whenever (or whether) .batch is first read
+                missing = [p for p in q.properties
+                           if p not in st.batch.columns]
+                if missing:
+                    raise KeyError(f"unknown propert"
+                                   f"{'ies' if len(missing) > 1 else 'y'}: "
+                                   f"{', '.join(missing)}")
+            batch: Any = _LazyBatch(st.batch, idx, q.properties,
+                                    row_order=q.sort_by is None)
+            st.live_lazy.add(batch)
+            if attr_mask is not None:
+                # null unauthorized attribute values in the result rows
+                # (KryoVisibilityRowEncoder: the row is assembled from the
+                # cells the scanner's auths can see)
+                m = attr_mask
+                if not m.all():
+                    mb = batch.materialize() if isinstance(batch, _LazyBatch) \
+                        else batch
+                    by_name = {a.name: j
+                               for j, a in enumerate(st.sft.attributes)}
+                    cols = {}
+                    for a in mb.sft.attributes:
+                        col = mb.col(a.name)
+                        bad = ~m[:, by_name[a.name]]
+                        cols[a.name] = (_null_cells(col, bad) if bad.any()
+                                        else col)
+                    batch = FeatureBatch(mb.sft, mb.ids, cols)
+            if isinstance(batch, FeatureBatch):
+                # attr-visibility path materialized already; reuse its ids
+                ids = batch.ids
+            elif len(idx) <= 100_000:
+                # eager id gather (the result's identity), lazy columns:
+                # id-only consumers — count checks, bench loops, join sides
+                # — never pay the per-column copies, and .batch still
+                # materializes on first read (the reference's readers are
+                # lazy over their scan buffers the same way,
+                # KryoBufferSimpleFeature). The result pins the immutable
+                # column snapshot until dropped.
+                ids = st.batch.ids[idx]
+            else:
+                # deferred gather against the immutable batch snapshot:
+                # large results are often consumed via batch columns (or
+                # only counted) and never read ids at all
+                src = st.batch
+                ids = (lambda: src.ids[idx])
+            explain(f"Hits: {len(idx)}").pop()
+            scan_s = time.perf_counter() - t_scan0
+            from ..metrics import metrics as _metrics
+            _metrics.observe("store.scan", scan_s,
+                             labels={"type": q.type_name,
+                                     "index": strategy.index or "none"})
+            from ..obs.slo import slo_engine
+            slo_engine.record("store.scan", ok=True, latency_s=scan_s)
+            from ..audit import audit_query
+            audit_query(self.audit, "memory", q.type_name, str(q.filter),
+                        q.hints, t_plan * 1000, scan_s * 1000, len(idx),
+                        index=strategy.index, rows_scanned=int(st.n),
+                        batched=batched)
+            sp.set_attr(ids_eager=not callable(ids))
+            return QueryResult(ids, batch, explain, strategy, n=len(idx),
+                               scan_span=scan_span)
 
     @_synchronized
     def query_count(self, q: Query | str,
@@ -1317,7 +1333,6 @@ class InMemoryDataStore(DataStore):
         explain = Explainer()
         explain.push(lambda: f"Counting '{q.type_name}' "
                              f"filter={q.filter}")
-        from ..obs import tracer
         with tracer.span("store-scan", q.type_name) as sp:
             idx, strategy, t_plan, t_scan0, _m = \
                 self._matching_rows(q, st, explain)
@@ -1389,7 +1404,6 @@ class InMemoryDataStore(DataStore):
             if not fused:
                 continue
             t_scan0 = _time.perf_counter()
-            from ..obs import tracer
             with tracer.span("store-scan", tn) as sp:
                 sp.set_attr(fused=len(fused), rows=int(st.n))
                 rows_per_q = self._batched_scan_rows(
@@ -1488,13 +1502,17 @@ class InMemoryDataStore(DataStore):
         gathered candidates otherwise."""
         from ..scan import residual
         batch = st.batch
-        if (len(idx) * 4 > st.n
-                and residual.is_compilable(residual_f, batch)):
-            explain("Device residual scan (dense)")
-            mask = np.asarray(residual.device_mask(residual_f, batch,
-                                                   st.device_cols()))
-            return idx[mask[idx]]
-        return idx[evaluate(residual_f, batch.take(idx))]
+        with tracer.span("residual") as sp:
+            if (len(idx) * 4 > st.n
+                    and residual.is_compilable(residual_f, batch)):
+                sp.set_attr(where="device", rows=int(len(idx)), columns=0)
+                explain("Device residual scan (dense)")
+                mask = np.asarray(residual.device_mask(residual_f, batch,
+                                                       st.device_cols()))
+                return idx[mask[idx]]
+            sp.set_attr(where="host", rows=int(len(idx)),
+                        columns=len(batch.columns))
+            return idx[evaluate(residual_f, batch.take(idx))]
 
     def _attr_scan(self, st: _TypeState, strategy: FilterStrategy,
                    explain: Explainer) -> np.ndarray:
@@ -1595,8 +1613,11 @@ class InMemoryDataStore(DataStore):
         from ..index.zkeys import SCAN_BLOCK_THRESHOLD, search_rows
         block_cap = int(float(SCAN_BLOCK_THRESHOLD.get()) * st.n)
         host_cap = min(block_cap, int(HOST_SCAN_ROWS.get()))
-        kind, res_rows = search_rows(st.zindex, strategy.index, boxes,
-                                     intervals, host_cap, block_cap)
+        with tracer.span("index-search") as sp:
+            kind, res_rows = search_rows(st.zindex, strategy.index, boxes,
+                                         intervals, host_cap, block_cap)
+            sp.set_attr(outcome=kind or "dense",
+                        rows=0 if res_rows is None else int(len(res_rows)))
         idx_exact = res_rows if kind == "exact" else None
         rows = res_rows if kind == "candidates" else None
 
@@ -1651,6 +1672,7 @@ class InMemoryDataStore(DataStore):
         (rows outside a pruned candidate set are provably outside
         the query in exact f64, so patching the subset is exact)."""
         cand = zscan.boundary_candidates(xhi, yhi, sq)
+        tracer.current_span().set_attr(checked=int(len(cand)))
         if not len(cand):
             return mask
         batch = st.batch
@@ -1671,27 +1693,50 @@ class InMemoryDataStore(DataStore):
         gathered rows + boundary patch on the subset."""
         explain(f"Index-pruned device scan: {len(rows)} candidate "
                 f"row(s) of {st.n}, {nb} box(es), {ni} interval(s)")
-        sub = zscan.scan_mask_at(st.scan_data, sq, rows)
-        sub = self._patch_mask(st, sub, st.host_xhi[rows],
-                               st.host_yhi[rows], rows, sq, explain)
-        return np.sort(rows[sub])
+        m = len(rows)
+        k = zscan.next_pow2(m) if m else 0
+        # up: the padded row list as 32-bit device indices; down: k bools
+        h2d, d2h = 4 * k, k
+        with tracer.span("gather-scan") as sp:
+            sp.set_attr(candidates=m, padded=k, h2d_bytes=h2d,
+                        d2h_bytes=d2h)
+            t0 = time.perf_counter()
+            sub = zscan.scan_mask_at(st.scan_data, sq, rows)
+            if k:
+                runtime.note_dispatch("scan", ("gathered", k),
+                                      time.perf_counter() - t0,
+                                      h2d_bytes=h2d, d2h_bytes=d2h)
+        with tracer.span("boundary-patch"):
+            sub = self._patch_mask(st, sub, st.host_xhi[rows],
+                                   st.host_yhi[rows], rows, sq, explain)
+            return np.sort(rows[sub])
 
     def _scan_dense(self, st: _TypeState, sq: zscan.ScanQuery,
                     explain: Explainer, nb: int, ni: int) -> np.ndarray:
         """Dense full-batch tier: the flag-selected XLA or Pallas
         kernel + full-table boundary patch."""
-        if SCAN_KERNEL.get() == "pallas":
-            from ..scan.pallas_scan import pallas_scan_mask
-            explain(f"Pallas device scan: {nb} box(es), "
-                    f"{ni} interval(s), n={st.n}")
-            mask = pallas_scan_mask(st.pallas(), sq)
-        else:
-            explain(f"Device scan: {nb} box(es), "
-                    f"{ni} interval(s), n={st.n}")
-            mask = np.asarray(zscan.scan_mask(st.scan_data, sq))[:st.n]
-        mask = self._patch_mask(st, mask, st.host_xhi, st.host_yhi,
-                                None, sq, explain)
-        return np.flatnonzero(mask)
+        with tracer.span("dense-scan") as sp:
+            t0 = time.perf_counter()
+            if SCAN_KERNEL.get() == "pallas":
+                from ..scan.pallas_scan import LANES, pallas_scan_mask
+                explain(f"Pallas device scan: {nb} box(es), "
+                        f"{ni} interval(s), n={st.n}")
+                data = st.pallas()
+                mask = pallas_scan_mask(data, sq)
+                padded = data.rows * LANES      # one int8 a row comes down
+            else:
+                explain(f"Device scan: {nb} box(es), "
+                        f"{ni} interval(s), n={st.n}")
+                padded = st.scan_data.cap       # one bool a row
+                mask = np.asarray(zscan.scan_mask(st.scan_data, sq))[:st.n]
+            sp.set_attr(rows=int(st.n), d2h_bytes=int(padded))
+            runtime.note_dispatch("scan", ("dense", int(padded)),
+                                  time.perf_counter() - t0,
+                                  d2h_bytes=int(padded))
+        with tracer.span("boundary-patch"):
+            mask = self._patch_mask(st, mask, st.host_xhi, st.host_yhi,
+                                    None, sq, explain)
+            return np.flatnonzero(mask)
 
     def _device_extent_scan(self, st: _TypeState, q: Query,
                             strategy: FilterStrategy,

@@ -24,8 +24,11 @@ CACHE_DIR = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
 def ensure_compile_cache() -> None:
-    """Point JAX at the persistent compilation cache (called when each
-    kernel module is imported; repeated calls set the same values)."""
+    """Point JAX at the persistent compilation cache and count its backend
+    compiles (called when each kernel module is imported; repeated calls
+    set the same values)."""
+    from ..obs.runtime import runtime
+    runtime.watch_compiles()
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
     # cache everything that took meaningful compile time; the default

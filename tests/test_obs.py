@@ -340,6 +340,21 @@ class TestAuditHook:
         assert e.index is not None
         assert e.trace_id is None  # tracing off never blocks auditing
 
+    def test_batched_flag_comes_from_the_call(self, sampled):
+        """A fused batch audits each query batched=True; a trace flag
+        named ``batched`` (nothing sets one) does not make a scalar query
+        batched."""
+        from geomesa_tpu.obs import set_flag
+        log = AuditLogger()
+        ds = seeded_store(audit=log)
+        qs = [Query("pts", "BBOX(geom, -60, -50, 0, 0)"),
+              Query("pts", "BBOX(geom, 0, 0, 60, 50)")]
+        ds.query_batched(qs)
+        with tracer.span("web", "t", root=True):
+            set_flag("batched")
+            ds.query(qs[0])
+        assert [e.batched for e in log.query()] == [True, True, False]
+
 
 # -- trace core ------------------------------------------------------------
 
