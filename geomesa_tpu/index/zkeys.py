@@ -16,11 +16,11 @@ permutation**.  Planning a query:
 
 The candidate set is a strict over-approximation of the true matches
 (range decomposition over-covers, exactly like the reference, which
-re-checks every row server-side with Z3Filter); the fused device kernel
-then evaluates the exact predicate on just the gathered candidates.
+re-checks every row server-side with Z3Filter); the fused device kernel's
+mask is then read at just the candidate rows, and only they are patched.
 When the candidate set is a large fraction of the table the store falls
-back to the full-batch scan — a gather of most rows costs more than a
-dense scan (the cost crossover the reference handles with
+back to the full-batch scan and patch, without a candidate list (the
+cost crossover the reference handles with
 ``QueryProperties.SCAN_RANGES_TARGET`` coarsening).
 
 Index build is lazy per curve (z3 and z2 orders are built on first use,
@@ -662,7 +662,7 @@ class ZKeyIndex:
         ("exact", rows) when the candidate positions fit ``host_cap``
         (exact evaluation over sorted-order coordinate copies —
         sequential access), ("candidates", rows) when they fit only
-        ``block_cap`` (caller runs the gathered device scan), or
+        ``block_cap`` (the caller's candidate tier), or
         (None, None) for the dense path. ``cache=False`` neither reads
         nor writes the decomposition cache (one-shot probe boxes)."""
         use_z3 = index_name == "z3" and bool(intervals_ms)

@@ -47,7 +47,8 @@ from ..utils.threads import ThreadManagement
 # process-wide query reaper (ThreadManagement.scala's 5s sweep)
 _REAPER = ThreadManagement()
 
-# dense-scan kernel selection: "xla" (default) or "pallas" — the
+# dense z3 kernel selection (the dense and candidate tiers both run
+# it): "xla" (default) or "pallas" — the
 # hand-tiled kernel (scan/pallas_scan.py) is numerically identical and
 # parity-tested; the flag mirrors the reference's pluggable iterator
 # stack selection (AccumuloIndexAdapter.scanConfig choosing iterators)
@@ -549,7 +550,7 @@ class _TypeState:
             millis = np.zeros(len(x), dtype=np.int64)
         self._build_point_index(x, y, millis)
         # host sorted z-key index for range pruning (lazy per curve);
-        # Z3IndexKeySpace.getRanges analog feeding the gathered scan
+        # Z3IndexKeySpace.getRanges analog feeding the scan tiers
         from ..index.zkeys import ZKeyIndex
         self.zindex = ZKeyIndex(x, y,
                                 millis if dtg is not None else None,
@@ -1595,7 +1596,7 @@ class InMemoryDataStore(DataStore):
                      strategy: FilterStrategy, explain: Explainer,
                      art: "_PlanArtifacts | None" = None) -> np.ndarray:
         """The hot path: z-range index pruning -> fused device kernel
-        (gathered candidates or dense) + exact boundary patch +
+        (read at the candidate rows, or dense) + exact boundary patch +
         non-envelope geometry residual. Returns sorted row indices."""
         sft = st.sft
         batch = st.batch
@@ -1607,8 +1608,9 @@ class InMemoryDataStore(DataStore):
         # z-range pruning (Z3IndexKeySpace.getRanges analog): the host
         # fast path resolves selective queries EXACTLY inside the index
         # (sequential passes over sorted-order coordinate copies); wider
-        # candidate sets fall to the gathered device scan, and beyond
-        # the block threshold to the dense full-batch kernel. One
+        # candidate sets fall to the candidate tier (the dense kernel's
+        # mask read at the candidate rows, patched on them alone), and
+        # beyond the block threshold to the dense tier. One
         # decomposition serves all tiers (zkeys.search_rows).
         from ..index.zkeys import SCAN_BLOCK_THRESHOLD, search_rows
         block_cap = int(float(SCAN_BLOCK_THRESHOLD.get()) * st.n)
@@ -1686,26 +1688,41 @@ class InMemoryDataStore(DataStore):
         explain(f"Boundary recheck: {len(cand)} candidate(s)")
         return zscan.exact_patch(mask, cand, x, y, millis, sq)
 
+    @staticmethod
+    def _dense_mask(st: _TypeState, sq: zscan.ScanQuery,
+                    kernel: str) -> tuple[np.ndarray, int]:
+        """The ``kernel`` ("xla" or "pallas") z3 pass over every row:
+        (host bool[n] two-float mask, rows scanned). One byte a scanned
+        row comes down."""
+        if kernel == "pallas":
+            from ..scan.pallas_scan import LANES, pallas_scan_mask
+            data = st.pallas()
+            return pallas_scan_mask(data, sq), int(data.rows * LANES)
+        mask = np.asarray(zscan.scan_mask(st.scan_data, sq))[:st.n]
+        return mask, int(st.scan_data.cap)
+
     def _scan_gathered(self, st: _TypeState, sq: zscan.ScanQuery,
                        rows: np.ndarray, explain: Explainer,
                        nb: int, ni: int) -> np.ndarray:
-        """Index-pruned candidate tier: fused kernel over just the
-        gathered rows + boundary patch on the subset."""
+        """Index-pruned candidate tier: the dense z3 pass, its mask read
+        at the candidate rows on the host + boundary patch on the subset.
+        A row's verdict depends on its own values alone, so this is the
+        mask of a scan over just those rows, without a device gather of
+        random rows, which costs hundreds of dense passes."""
         explain(f"Index-pruned device scan: {len(rows)} candidate "
                 f"row(s) of {st.n}, {nb} box(es), {ni} interval(s)")
         m = len(rows)
-        k = zscan.next_pow2(m) if m else 0
-        # up: the padded row list as 32-bit device indices; down: k bools
-        h2d, d2h = 4 * k, k
         with tracer.span("gather-scan") as sp:
-            sp.set_attr(candidates=m, padded=k, h2d_bytes=h2d,
-                        d2h_bytes=d2h)
             t0 = time.perf_counter()
-            sub = zscan.scan_mask_at(st.scan_data, sq, rows)
-            if k:
-                runtime.note_dispatch("scan", ("gathered", k),
+            sub, scanned = np.zeros(0, dtype=bool), 0
+            if m:
+                mask, scanned = self._dense_mask(st, sq, SCAN_KERNEL.get())
+                sub = mask[rows]
+                runtime.note_dispatch("scan", ("gathered", scanned),
                                       time.perf_counter() - t0,
-                                      h2d_bytes=h2d, d2h_bytes=d2h)
+                                      d2h_bytes=scanned)
+            sp.set_attr(candidates=m, padded=scanned, h2d_bytes=0,
+                        d2h_bytes=scanned)
         with tracer.span("boundary-patch"):
             sub = self._patch_mask(st, sub, st.host_xhi[rows],
                                    st.host_yhi[rows], rows, sq, explain)
@@ -1717,22 +1734,15 @@ class InMemoryDataStore(DataStore):
         kernel + full-table boundary patch."""
         with tracer.span("dense-scan") as sp:
             t0 = time.perf_counter()
-            if SCAN_KERNEL.get() == "pallas":
-                from ..scan.pallas_scan import LANES, pallas_scan_mask
-                explain(f"Pallas device scan: {nb} box(es), "
-                        f"{ni} interval(s), n={st.n}")
-                data = st.pallas()
-                mask = pallas_scan_mask(data, sq)
-                padded = data.rows * LANES      # one int8 a row comes down
-            else:
-                explain(f"Device scan: {nb} box(es), "
-                        f"{ni} interval(s), n={st.n}")
-                padded = st.scan_data.cap       # one bool a row
-                mask = np.asarray(zscan.scan_mask(st.scan_data, sq))[:st.n]
-            sp.set_attr(rows=int(st.n), d2h_bytes=int(padded))
-            runtime.note_dispatch("scan", ("dense", int(padded)),
+            kernel = SCAN_KERNEL.get()
+            label = "Pallas device" if kernel == "pallas" else "Device"
+            explain(f"{label} scan: {nb} box(es), {ni} interval(s), "
+                    f"n={st.n}")
+            mask, padded = self._dense_mask(st, sq, kernel)
+            sp.set_attr(rows=int(st.n), d2h_bytes=padded)
+            runtime.note_dispatch("scan", ("dense", padded),
                                   time.perf_counter() - t0,
-                                  d2h_bytes=int(padded))
+                                  d2h_bytes=padded)
         with tracer.span("boundary-patch"):
             mask = self._patch_mask(st, mask, st.host_xhi, st.host_yhi,
                                     None, sq, explain)

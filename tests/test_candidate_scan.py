@@ -1,0 +1,106 @@
+"""Candidate tier: the dense z3 mask read at the candidate rows gives the
+same ids as a scan of just those rows (``zscan.scan_mask_at``) and as the
+f64 filter, with points on and one ulp beside every box edge and time
+bound, under both dense kernels."""
+
+import numpy as np
+import pytest
+
+from geomesa_tpu.features import parse_spec
+from geomesa_tpu.filters import evaluate, parse_ecql
+from geomesa_tpu.index.api import Query
+from geomesa_tpu.index.zkeys import SCAN_BLOCK_THRESHOLD
+from geomesa_tpu.scan import zscan
+from geomesa_tpu.store import InMemoryDataStore
+from geomesa_tpu.store.memory import HOST_SCAN_ROWS, SCAN_KERNEL
+
+MS = lambda s: int(np.datetime64(s, "ms").astype(np.int64))
+
+T0, T1 = "2020-02-03T04:05:06.789Z", "2020-03-07T08:09:10.111Z"
+WINDOW = f"dtg DURING {T0}/{T1}"
+BOXES = [(-100.3, -60.7, 100.1, 60.3), (120.01, -20.2, 150.7, 33.3)]
+
+
+def _edge_points(rng, boxes, lo, hi):
+    """Points on, and one ulp either side of, each box edge and each
+    time bound; the other coordinate and the time drawn inside."""
+    xs, ys, ts = [], [], []
+    for xmin, ymin, xmax, ymax in boxes:
+        for e in (xmin, xmax, ymin, ymax):
+            for v in (np.nextafter(e, -np.inf), e, np.nextafter(e, np.inf)):
+                for _ in range(4):
+                    x, y = rng.uniform(xmin, xmax), rng.uniform(ymin, ymax)
+                    if e in (xmin, xmax):
+                        x = v
+                    else:
+                        y = v
+                    xs.append(x), ys.append(y)
+                    ts.append(rng.integers(lo, hi))
+        for t in (lo - 1, lo, lo + 1, hi - 1, hi, hi + 1):
+            for _ in range(4):
+                xs.append(rng.uniform(xmin, xmax))
+                ys.append(rng.uniform(ymin, ymax))
+                ts.append(t)
+    return np.array(xs), np.array(ys), np.array(ts, dtype=np.int64)
+
+
+@pytest.fixture(scope="module")
+def ds():
+    rng = np.random.default_rng(24)
+    n = 20_000
+    lo, hi = MS(T0[:-1]), MS(T1[:-1])
+    ex, ey, et = _edge_points(rng, BOXES, lo, hi)
+    x = np.concatenate([rng.uniform(-180, 180, n), ex])
+    y = np.concatenate([rng.uniform(-90, 90, n), ey])
+    t = np.concatenate([rng.integers(MS("2020-01-01"), MS("2020-05-01"), n),
+                        et])
+    ds = InMemoryDataStore()
+    ds.create_schema(parse_spec("pts", "dtg:Date,*geom:Point:srid=4326"))
+    ds.write_dict("pts", [f"p{i}" for i in range(len(x))],
+                  {"dtg": t, "geom": (x, y)})
+    return ds
+
+
+@pytest.fixture
+def candidate_tier():
+    HOST_SCAN_ROWS.set("10")
+    SCAN_BLOCK_THRESHOLD.set("0.9")
+    try:
+        yield
+    finally:
+        for p in (HOST_SCAN_ROWS, SCAN_BLOCK_THRESHOLD, SCAN_KERNEL):
+            p.set(None)
+
+
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+@pytest.mark.parametrize("timed", [False, True], ids=["no-time", "time"])
+@pytest.mark.parametrize("nboxes", [1, 2])
+def test_candidate_tier_matches_row_scan_and_f64(ds, candidate_tier,
+                                                 monkeypatch, kernel, timed,
+                                                 nboxes):
+    SCAN_KERNEL.set(kernel)
+    bbox = " OR ".join(f"BBOX(geom, {a}, {b}, {c}, {d})"
+                       for a, b, c, d in BOXES[:nboxes])
+    ecql = f"({bbox}) AND {WINDOW}" if timed else bbox
+    seen = []
+    tier = type(ds)._scan_gathered
+
+    def spy(self, st, sq, rows, explain, nb, ni):
+        idx = tier(self, st, sq, rows, explain, nb, ni)
+        seen.append((st, sq, rows, idx))
+        return idx
+
+    monkeypatch.setattr(type(ds), "_scan_gathered", spy)
+    lines = []
+    res = ds.query(Query("pts", ecql), explain_out=lines.append)
+    assert any(ln.strip().startswith("Index-pruned device scan:")
+               for ln in lines), lines
+    ((st, sq, rows, idx),) = seen
+    assert len(rows) > 0
+    sub = ds._patch_mask(st, zscan.scan_mask_at(st.scan_data, sq, rows),
+                         st.host_xhi[rows], st.host_yhi[rows], rows, sq,
+                         lines.append)
+    np.testing.assert_array_equal(idx, np.sort(rows[sub]))
+    want = np.flatnonzero(evaluate(parse_ecql(ecql), st.batch))
+    np.testing.assert_array_equal(idx, want)
+    assert set(res.ids.astype(str)) == set(st.batch.ids[want].astype(str))

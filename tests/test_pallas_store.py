@@ -33,8 +33,8 @@ QUERIES = [
     ("BBOX(geom, -180, -90, 0, 90) OR BBOX(geom, 10, 10, 180, 90)", True),
     ("BBOX(geom, -180, -90, 180, 90) AND "
      "dtg DURING 2020-01-05T00:00:00Z/2020-05-20T00:00:00Z", True),
-    # selective queries ride the pruned gather path (flag-independent)
-    # but must stay correct with the flag set
+    # selective queries ride the index-pruned tiers and must stay
+    # correct with the flag set
     ("BBOX(geom, -10, -10, 10, 10)", False),
     ("BBOX(geom, -180, -90, 180, 90) AND "
      "dtg DURING 2020-02-01T00:00:00Z/2020-02-20T00:00:00Z", False),

@@ -105,9 +105,9 @@ def test_each_tier_yields_its_steps(ds, sampled, tier):
     if tier == "gathered":
         g = kids["gather-scan"]["attrs"]
         assert g["candidates"] == search["rows"] > 0
-        k = g["padded"]
-        assert k >= g["candidates"] and k & (k - 1) == 0
-        assert g["h2d_bytes"] == 4 * k and g["d2h_bytes"] == k
+        # the dense pass scans every row; its mask comes down, a byte a row
+        assert g["padded"] >= 20_000 > g["candidates"]
+        assert g["h2d_bytes"] == 0 and g["d2h_bytes"] == g["padded"]
     if tier in ("dense", "pallas"):
         d = kids["dense-scan"]["attrs"]
         assert d["rows"] == 20_000 and d["d2h_bytes"] >= 20_000
