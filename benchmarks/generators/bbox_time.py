@@ -238,10 +238,13 @@ def warmup(ctx, mix: dict, seed: int) -> int:
     kernel at each power-of-two row count it pads candidates to between
     the host tier's cap and the dense tier (called as the store calls it:
     boxes found by the plan reach these classes only by chance), and a few
-    of the mix's own queries. No answer is read: only the timed call
-    compiles. Returns the number of requests sent."""
+    of the mix's own queries. A mesh store evaluates its candidates on the
+    host and takes the dense tier's hit-row sizes instead (``_warm_mesh``).
+    No answer is read: only the timed call compiles. Returns the number of
+    requests sent."""
     from geomesa_tpu.index.zkeys import SCAN_BLOCK_THRESHOLD
     from geomesa_tpu.scan import zscan
+    from geomesa_tpu.store import DistributedDataStore
     from geomesa_tpu.store.memory import HOST_SCAN_ROWS
     s = Stream(mix, ctx.table, seed, stream=4)
     days = (s.t_hi - s.t_lo) / MS_DAY
@@ -250,20 +253,38 @@ def warmup(ctx, mix: dict, seed: int) -> int:
     n = ctx.table.n
     lo = int(HOST_SCAN_ROWS.get())
     hi = float(SCAN_BLOCK_THRESHOLD.get()) * n
-    try:
-        data = ctx.store._state(ctx.type_name).scan_data
-        sq = zscan.make_query([WORLD], [(s.t_lo, s.t_hi)])
-        k = lo.bit_length()
-        while (1 << (k - 1)) < hi:
-            zscan.scan_mask_at(data, sq, np.arange(min(1 << k, n),
-                                                   dtype=np.int32))
-            k += 1
-    except (AttributeError, TypeError) as e:   # the store's tiers moved
-        print(f"warm-up: gathered classes not warmed ({e!r})",
-              file=sys.stderr)
+    if isinstance(ctx.store, DistributedDataStore):
+        _warm_mesh(ctx, s, lo)
+    else:
+        try:
+            data = ctx.store._state(ctx.type_name).scan_data
+            sq = zscan.make_query([WORLD], [(s.t_lo, s.t_hi)])
+            k = lo.bit_length()
+            while (1 << (k - 1)) < hi:
+                zscan.scan_mask_at(data, sq, np.arange(min(1 << k, n),
+                                                       dtype=np.int32))
+                k += 1
+        except (AttributeError, TypeError) as e:  # the store's tiers moved
+            print(f"warm-up: gathered classes not warmed ({e!r})",
+                  file=sys.stderr)
     for _ in range(WARM_MIX):
         submit(ctx, next(s))
     return 1 + WARM_MIX
+
+
+def _warm_mesh(ctx, s: Stream, lo: int):
+    """The mesh store's dense tier compacts its sharded mask into a buffer
+    of hit rows padded to a power of two: warm each size from the host
+    tier's cap to the whole table, called as the store calls it."""
+    from geomesa_tpu.parallel import mesh
+    from geomesa_tpu.scan import zscan
+    sq = zscan.make_query([WORLD], [(s.t_lo, s.t_hi)])
+    for seg in ctx.store._state(ctx.type_name).segments:
+        mask = mesh.distributed_scan_mask(seg, sq)
+        k = max(lo - 1, 1).bit_length()
+        while (1 << (k - 1)) < seg.n:
+            mesh._mask_hit_rows(mask, 1 << k).block_until_ready()
+            k += 1
 
 
 def submit(ctx, q: Request):
@@ -285,7 +306,10 @@ def size(got) -> int:
 
 def facts(ctx, q: Request, res) -> dict:
     """The tier the plan chose, its candidates, whether the device residual
-    ran, and the predicates' types: what the per-layer readers need."""
+    ran, and the predicates' types: what the per-layer readers need. The
+    mesh store's tiers have names of their own (``host-candidates``, the
+    f64 host scan of the index's candidates; ``mesh-dense``, the sharded
+    scan), so no reader counts them as the single chip's work."""
     ex = getattr(res, "explain", None)
     text = ex.text if ex is not None else ""
     tier, cand = "other", 0
@@ -295,8 +319,12 @@ def facts(ctx, q: Request, res) -> dict:
             tier = "host"
         elif ln.startswith("Index-pruned device scan:"):
             tier, cand = "gathered", int(ln.split(":")[1].split()[0])
+        elif ln.startswith("Index-pruned host candidate scan:"):
+            tier, cand = "host-candidates", int(ln.split(":")[1].split()[0])
         elif ln.startswith(("Device scan:", "Pallas device scan:")):
             tier = "dense"
+        elif ln.startswith("Distributed scan over"):
+            tier = "mesh-dense"
         elif ln.startswith("Batched"):
             tier = "batched"
         else:

@@ -20,14 +20,20 @@ answer)`` (numbers compared with its plain reference, each held to its
 entry in ``LIMITS``) and ``control(table, q)`` (the reference in lower
 precision in the program's place).
 
-A run generates the table from the seed, loads it through
-``InMemoryDataStore.write_dict`` on one chip, warms up on the generator's
-set-up requests, then lets the mix's clients send requests in a closed
-loop for ``--seconds``. A request is timed from submit until its answer is
-in hand. After the window, the answers (all of them, or a sample drawn from
-the seed that holds the largest) are checked against the reference; the
-numbers compared and their limits are printed last on stderr and under
-``compared`` in the result line.
+A configuration names the store that holds it under ``"store"``:
+``"memory"`` (the default) is ``InMemoryDataStore`` on one chip, ``"mesh"``
+is ``DistributedDataStore`` over ``data_mesh(chips)``, the cell's chips.
+A mesh store needs a cell of two chips or more, and a memory store a cell
+of one: any other pairing ends the run with no result.
+
+A run generates the table from the seed, loads it through the store's
+``write_dict``, warms up on the generator's set-up requests, then lets the
+mix's clients send requests in a closed loop for ``--seconds``. A request
+is timed from submit until its answer is in hand. After the window, the
+answers (all of them, or a sample drawn from the seed that holds the
+largest) are checked against the reference; the numbers compared and their
+limits are printed last on stderr and under ``compared`` in the result
+line.
 """
 
 from __future__ import annotations
@@ -57,21 +63,32 @@ import roofline  # noqa: E402
 import trace_reduce  # noqa: E402
 
 JOIN_GRACE_S = 120   # a request in flight at the close may finish this late
+STORES = ("memory", "mesh")
+MESH_COLUMNS = ("xhi", "xlo", "yhi", "ylo", "tday", "tms")
 
 
 class Usage(Exception):
     pass
 
 
-def load_cell(name: str) -> types.SimpleNamespace:
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
-        bench = json.load(fh)
+def load_cell(name: str, bench: dict | None = None):
+    """The cell ``name`` of ``bench`` (by default ``BENCHMARK.json``)."""
+    if bench is None:
+        with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+            bench = json.load(fh)
     cell = next((w for w in bench["workloads"] if w["name"] == name), None)
     if cell is None:
         raise Usage(f"no workload {name!r} in BENCHMARK.json")
     conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
     with open(os.path.join(ROOT, conf["file"])) as fh:
         config = json.load(fh)
+    store = config.get("store", "memory")
+    if store not in STORES:
+        raise Usage(f"configuration {conf['name']!r} names store {store!r}; "
+                    f"one of {', '.join(STORES)}")
+    if (store == "mesh") != (cell["chips"] > 1):
+        raise Usage(f"a {store} store in a cell of {cell['chips']} chip(s): "
+                    "a mesh takes two or more, a memory store one")
     with open(os.path.join(HERE, "traffic", cell["traffic"] + ".json")) as fh:
         mix = json.load(fh)
     gen = _module("generators", mix["generator"])
@@ -125,15 +142,33 @@ class Compiles:
             self.secs += duration
 
 
-def load_store(table, config):
+def load_store(table, config, chips: int):
+    """The configuration's store, loaded with the table: the row count is
+    checked, and a mesh store's device columns must sit on ``chips``
+    distinct devices."""
     from geomesa_tpu.features import parse_spec
-    from geomesa_tpu.store import InMemoryDataStore
-    ds = InMemoryDataStore()
-    sft = parse_spec(config["type_name"], config["spec"])
-    ds.create_schema(sft)
-    ds.write_dict(config["type_name"], table.ids, datagen.to_store(table))
-    if ds.count(config["type_name"]) != table.n:
+    from geomesa_tpu.store import DistributedDataStore, InMemoryDataStore
+    mesh = config.get("store", "memory") == "mesh"
+    if mesh:
+        from geomesa_tpu.parallel import data_mesh
+        ds = DistributedDataStore(data_mesh(chips))
+    else:
+        ds = InMemoryDataStore()
+    name = config["type_name"]
+    ds.create_schema(parse_spec(name, config["spec"]))
+    ds.write_dict(name, table.ids, datagen.to_store(table))
+    if ds.count(name) != table.n:
         raise RuntimeError("the store holds another row count than written")
+    if mesh:
+        st = ds._state(name)
+        st.ensure_index()
+        for seg in st.segments:
+            for c in MESH_COLUMNS:
+                on = {s.device for s in getattr(seg, c).addressable_shards
+                      if s.data.size}
+                if len(on) != chips:
+                    raise RuntimeError(f"mesh column {c} sits on {len(on)} "
+                                       f"device(s), not {chips}")
     return ds
 
 
@@ -220,17 +255,18 @@ def compare(gen, table, records, seed: int, cap: int):
 
 
 def run(argv=None, *, require_chip: bool = True, rows: int | None = None,
-        control: bool = False) -> dict:
-    """One run; returns the result object. ``rows`` and ``control`` exist
-    for the CPU rehearsal: a row cut, and the generator's control answering
-    in the program's place."""
+        control: bool = False, bench: dict | None = None) -> dict:
+    """One run; returns the result object. ``rows``, ``control`` and
+    ``bench`` exist for the CPU rehearsal: a row cut, the generator's
+    control answering in the program's place, and a benchmark definition
+    in place of ``BENCHMARK.json``."""
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[1])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
-    cell = load_cell(args.workload)
+    cell = load_cell(args.workload, bench)
     os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(ROOT, ".jax_cache")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     # no cell runs a join: write_dict's join prewarm would compile kernels
@@ -255,7 +291,7 @@ def run(argv=None, *, require_chip: bool = True, rows: int | None = None,
     t0 = time.perf_counter()
     table = datagen.generate(config, args.seed, rows)
     t1 = time.perf_counter()
-    ds = load_store(table, config)
+    ds = load_store(table, config, cell.chips)
     t2 = time.perf_counter()
     ctx = types.SimpleNamespace(store=ds, batcher=shared_batcher(ds),
                                 type_name=config["type_name"], table=table)
