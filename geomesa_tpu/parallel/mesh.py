@@ -15,6 +15,9 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
+from collections.abc import Sequence
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +28,7 @@ from ..utils.jaxcache import ensure_compile_cache
 ensure_compile_cache()
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs import runtime, tracer
 from ..scan import zscan
 
 __all__ = ["data_mesh", "DistributedScanData", "shard_scan_data",
@@ -97,11 +101,13 @@ def shard_scan_data(x: np.ndarray, y: np.ndarray, millis: np.ndarray,
 
 
 def _shard_mask_fn(time_any: bool):
-    """Shard-local scan body; runs identically on every device."""
-    def body(xhi, xlo, yhi, ylo, tday, tms, boxes, box_valid, times, tvalid):
+    """Shard-local scan body; runs identically on every device. Its name
+    is the kernel's in the device trace (``jit__mesh_scan_mask``)."""
+    def _mesh_scan_mask(xhi, xlo, yhi, ylo, tday, tms, boxes, box_valid,
+                        times, tvalid):
         return zscan._scan_mask(xhi, xlo, yhi, ylo, tday, tms,
                                 boxes, box_valid, times, tvalid, time_any)
-    return body
+    return _mesh_scan_mask
 
 
 _SPECS_IN = (P("data"), P("data"), P("data"), P("data"),
@@ -155,37 +161,138 @@ def _mask_hit_rows(mask, cap):
     return jnp.nonzero(mask, size=cap, fill_value=mask.shape[0])[0]
 
 
-def exact_hit_rows(data: DistributedScanData,
-                   q: zscan.ScanQuery) -> np.ndarray:
-    """Sorted matching row ids with the exact f64 boundary patch —
-    count-then-compact on device, so host work and transfers are
-    O(hits + boundary candidates), never a full-length mask (the
-    materializing analog of distributed_count's psum shape)."""
+def _device_hit_rows(data: DistributedScanData,
+                     q: zscan.ScanQuery) -> tuple[np.ndarray, int]:
+    """Count-then-compact on device: (the sorted hit rows below
+    ``data.n`` as downloaded, the compaction's padded size)."""
     mask = distributed_scan_mask(data, q)
     # int32 is the real contract: single-table row counts are capped
     # below 2^31 (ZKeyIndex._perm_dtype)
     total = int(jnp.sum(mask, dtype=jnp.int32))
-    if total:
-        cap = 1 << (total - 1).bit_length()
-        rows = np.asarray(_mask_hit_rows(mask, cap)).astype(np.int64)
-        rows = rows[rows < data.n]
-    else:
-        rows = np.empty(0, dtype=np.int64)
-    # boundary patch in ROW-SET space: recompute the two-float verdict
-    # on host for just the boundary candidates, compare with exact f64,
-    # and add/remove the flipped rows
-    cand = zscan.boundary_candidates(data.host_xhi, data.host_yhi, q)
-    if len(cand):
-        dev, exact = _boundary_verdicts(data, q, cand)
-        add = cand[exact & ~dev]
-        remove = cand[dev & ~exact]
-        if len(remove):
-            rows = np.setdiff1d(rows, remove, assume_unique=True)
-        if len(add):
-            rows = np.union1d(rows, add)
-    # already sorted: nonzero indices ascend, setdiff1d preserves the
-    # (sorted) input order, union1d sorts
-    return rows
+    if not total:
+        return np.empty(0, dtype=np.int32), 0
+    cap = 1 << (total - 1).bit_length()
+    rows = np.asarray(_mask_hit_rows(mask, cap))
+    # the indices ascend; the fill value and padding rows are >= n
+    return rows[:np.searchsorted(rows, rows.dtype.type(data.n))], cap
+
+
+def _find(rows: np.ndarray, keys: np.ndarray) -> np.ndarray:
+    """``np.searchsorted(rows, keys)`` with the keys in the rows' dtype:
+    keys of a wider dtype would copy all of ``rows`` to compare."""
+    return np.searchsorted(rows, keys.astype(rows.dtype, copy=False))
+
+
+def _shard_candidates(data: DistributedScanData,
+                      q: zscan.ScanQuery) -> np.ndarray:
+    """``zscan.boundary_candidates`` over each shard's rows, the shards'
+    host passes side by side (numpy's compares release the GIL), as one
+    sorted array of row ids."""
+    if not data.n:
+        return np.empty(0, dtype=np.int64)
+    per = data.n_padded // data.mesh.devices.size
+    shards = [(lo, min(lo + per, data.n)) for lo in range(0, data.n, per)]
+
+    def one(shard):
+        lo, hi = shard
+        return lo + zscan.boundary_candidates(data.host_xhi[lo:hi],
+                                              data.host_yhi[lo:hi], q)
+
+    with ThreadPoolExecutor(len(shards)) as pool:
+        return np.concatenate(list(pool.map(one, shards)))
+
+
+def _boundary_edits(data: DistributedScanData, q: zscan.ScanQuery,
+                    rows: np.ndarray) -> tuple[int, np.ndarray, np.ndarray]:
+    """Boundary patch in ROW-SET space: the two-float verdict recomputed
+    on host for just the boundary candidates, compared with exact f64.
+    Returns (candidates checked, sorted rows to add, sorted rows to
+    remove) against the sorted device hits ``rows``."""
+    cand = _shard_candidates(data, q)
+    if not len(cand):
+        return 0, cand, cand
+    dev, exact = _boundary_verdicts(data, q, cand)
+    flip = dev != exact
+    rows_flip, want = cand[flip], exact[flip]
+    pos = _find(rows, rows_flip)
+    held = pos < len(rows)
+    held[held] = rows[pos[held]] == rows_flip[held]
+    return (len(cand), rows_flip[want & ~held], rows_flip[~want & held])
+
+
+def _splice(rows: np.ndarray, add: np.ndarray, remove: np.ndarray,
+            off: int, out: np.ndarray):
+    """``out[:] = sorted((rows - remove) | add) + off``, copied block by
+    block between the edits: all three are sorted, ``remove`` lies in
+    ``rows`` and ``add`` outside it. One pass over the hits."""
+    cut = _find(rows, remove)
+    at = _find(rows, add)
+    pos = np.concatenate([at, cut])
+    is_cut = np.arange(len(pos)) >= len(at)
+    i = j = 0
+    # in row order; at one position the insert goes before the removal
+    for e in np.lexsort((is_cut, pos)):
+        p = int(pos[e])
+        out[j:j + p - i] = rows[i:p]
+        j += p - i
+        if is_cut[e]:
+            i = p + 1
+        else:
+            out[j] = add[e]
+            i, j = p, j + 1
+    out[j:] = rows[i:]
+    if off:
+        out += off
+
+
+def exact_hit_rows(segments: Sequence[DistributedScanData],
+                   q: zscan.ScanQuery) -> np.ndarray:
+    """Sorted matching row ids over consecutive row segments (a segment's
+    rows follow those of the segments before it) with the exact f64
+    boundary patch: count-then-compact on device, so host work and
+    transfers are O(hits + boundary candidates), never a full-length
+    mask (the materializing analog of distributed_count's psum shape).
+
+    Span ``mesh-scan`` covers the shard-mapped pass, the hit count, the
+    compaction and the download of every segment; ``mesh-patch`` the
+    boundary candidates, their verdicts and the splice."""
+    segments = list(segments)
+    if not segments:
+        return np.empty(0, dtype=np.int32)
+    k = segments[0].mesh.devices.size
+    with tracer.span("mesh-scan") as sp:
+        t0 = time.perf_counter()
+        found = [_device_hit_rows(seg, q) for seg in segments]
+        cap = sum(c for _, c in found)
+        d2h = sum(c * r.itemsize for r, c in found)
+        shard_hits = np.zeros(k, dtype=np.int64)
+        for seg, (rows, _) in zip(segments, found):
+            bounds = np.arange(k + 1) * (seg.n_padded // k)
+            shard_hits += np.diff(_find(rows, bounds))
+        padded = sum(seg.n_padded for seg in segments)
+        runtime.note_dispatch("scan", ("mesh-dense", padded),
+                              time.perf_counter() - t0, d2h_bytes=d2h)
+        sp.set_attr(rows=sum(seg.n for seg in segments),
+                    segments=len(segments), shards=k,
+                    hits=int(shard_hits.sum()), cap=cap, d2h_bytes=d2h,
+                    shard_hits=shard_hits.tolist())
+    with tracer.span("mesh-patch") as sp:
+        edits = [_boundary_edits(seg, q, rows)
+                 for seg, (rows, _) in zip(segments, found)]
+        sizes = [len(rows) + len(add) - len(rm)
+                 for (rows, _), (_, add, rm) in zip(found, edits)]
+        # int32, as the compaction returns them: a table's rows are
+        # capped below 2^31 (ZKeyIndex._perm_dtype)
+        out = np.empty(sum(sizes), dtype=np.int32)
+        at = off = 0
+        for seg, (rows, _), (_, add, rm), m in zip(segments, found, edits,
+                                                  sizes):
+            _splice(rows, add, rm, off, out[at:at + m])
+            at, off = at + m, off + seg.n
+        sp.set_attr(checked=sum(e[0] for e in edits),
+                    added=sum(len(e[1]) for e in edits),
+                    removed=sum(len(e[2]) for e in edits))
+    return out
 
 
 def _boundary_verdicts(data: DistributedScanData, q: zscan.ScanQuery,
@@ -223,15 +330,17 @@ def _shard_batch_mask_fn():
     (two-float hi-cell collisions) computed in the same launch. Pad
     rows carry out-of-domain coords (1e9) so neither output can flag
     them; per-query time_any is absorbed into catch-all intervals by
-    zscan.stack_queries, so the temporal compare always runs."""
-    def body(xhi, xlo, yhi, ylo, tday, tms, boxes, box_valid, times, tvalid):
+    zscan.stack_queries, so the temporal compare always runs. The
+    kernel prints as ``jit__mesh_batch_scan_mask`` in the device trace."""
+    def _mesh_batch_scan_mask(xhi, xlo, yhi, ylo, tday, tms, boxes,
+                              box_valid, times, tvalid):
         def one(bx, bv, tx, tv):
             return (zscan._mask_body(xhi, xlo, yhi, ylo, tday, tms,
                                      bx, bv, tx, tv, time_any=False,
                                      n_valid=None),
                     zscan._cand_body(xhi, yhi, bx, bv))
         return jax.vmap(one)(boxes, box_valid, times, tvalid)
-    return body
+    return _mesh_batch_scan_mask
 
 
 @functools.lru_cache(maxsize=32)

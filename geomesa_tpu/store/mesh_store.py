@@ -40,6 +40,7 @@ from ..features.sft import SimpleFeatureType
 from ..filters import ast
 from ..filters.helper import extract_geometries
 from ..index.api import Explainer, Query, QueryHints
+from ..obs import tracer
 from ..parallel import (DistributedScanData, data_mesh, distributed_count,
                         distributed_density, distributed_histogram,
                         distributed_knn, distributed_tristate,
@@ -149,9 +150,12 @@ class DistributedDataStore(InMemoryDataStore):
         ms = (st.batch.col(st.sft.dtg_field).millis
               if intervals else None)
         boxes = [tuple(b) for b in sq.host_boxes]
-        keep = ZKeyIndex._eval_sorted(col.x, col.y, ms, rows, boxes,
-                                      intervals)
-        return np.sort(rows[keep])
+        with tracer.span("host-candidates") as sp:
+            keep = ZKeyIndex._eval_sorted(col.x, col.y, ms, rows, boxes,
+                                          intervals)
+            hits = np.sort(rows[keep])
+            sp.set_attr(rows=int(len(rows)), hits=int(len(hits)))
+        return hits
 
     def _scan_dense(self, st: _MeshTypeState, sq: zscan.ScanQuery,
                     explain: Explainer, nb: int, ni: int) -> np.ndarray:
@@ -162,11 +166,7 @@ class DistributedDataStore(InMemoryDataStore):
         explain(f"Distributed scan over {self.mesh.devices.size} "
                 f"device(s), {len(st.segments)} segment(s), n={st.n}, "
                 f"{nb} box(es), {ni} interval(s)")
-        offs = st.segment_offsets()[:-1]
-        parts = [exact_hit_rows(seg, sq) + off
-                 for seg, off in zip(st.segments, offs)]
-        return (np.concatenate(parts) if parts
-                else np.empty(0, dtype=np.int64))
+        return exact_hit_rows(st.segments, sq)
 
     def _batched_scan_rows(self, st: _MeshTypeState,
                            items) -> list[np.ndarray]:
