@@ -24,7 +24,7 @@ _lock = threading.Lock()
 _cache: dict = {}
 
 _SOURCES = ["feature_codec.cpp", "zrange.cpp", "zencode.cpp",
-            "zsort.cpp", "zbuild.cpp"]
+            "zsort.cpp", "zbuild.cpp", "mask.cpp"]
 
 
 def _source_files() -> list:

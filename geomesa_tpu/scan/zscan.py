@@ -34,10 +34,10 @@ ensure_compile_cache()
 
 __all__ = ["BatchedScanQuery", "DeviceScanData", "ScanQuery",
            "batch_hit_rows", "build_scan_data", "extend_scan_data",
-           "make_query", "next_pow2", "patch_hit_rows", "scan_mask",
-           "scan_mask_at", "scan_mask_batch", "scan_mask_batch_at",
-           "split_two_float", "stack_points", "stack_queries",
-           "MILLIS_PER_DAY"]
+           "hit_rows", "make_query", "next_pow2", "patch_hit_rows",
+           "scan_codes", "scan_mask", "scan_mask_at", "scan_mask_batch",
+           "scan_mask_batch_at", "split_two_float", "stack_points",
+           "stack_queries", "MILLIS_PER_DAY"]
 
 MILLIS_PER_DAY = 86_400_000
 
@@ -275,30 +275,38 @@ def _le_two_float(hi, lo, b_hi, b_lo):
 
 def _mask_body(xhi, xlo, yhi, ylo, tday, tms,
                boxes, box_valid, times, time_valid, time_any: bool,
-               n_valid=None):
+               n_valid=None, flag_boundary: bool = False):
+    """The two-float verdict per row: bool, or with ``flag_boundary`` a
+    uint8 code whose bit 0 is the verdict and bit 1 the boundary flag of
+    ``_cand_body`` (the same byte a row comes down either way)."""
     # spatial: any valid box contains the point — (n, K) broadcast
     bx = boxes[None, :, :]                      # (1, K, 8)
     sx = (_ge_two_float(xhi[:, None], xlo[:, None], bx[..., 0], bx[..., 1])
           & _le_two_float(xhi[:, None], xlo[:, None], bx[..., 2], bx[..., 3])
           & _ge_two_float(yhi[:, None], ylo[:, None], bx[..., 4], bx[..., 5])
           & _le_two_float(yhi[:, None], ylo[:, None], bx[..., 6], bx[..., 7]))
-    spatial = jnp.any(sx & box_valid[None, :], axis=1)
+    hit = jnp.any(sx & box_valid[None, :], axis=1)
     # capacity-padded rows (>= n_valid) are never matches
     if n_valid is not None:
-        spatial = spatial & (jnp.arange(xhi.shape[0]) < n_valid)
-    if time_any:
-        return spatial
-    tx = times[None, :, :]                      # (1, B, 4)
-    after_lo = ((tday[:, None] > tx[..., 0])
-                | ((tday[:, None] == tx[..., 0]) & (tms[:, None] >= tx[..., 1])))
-    before_hi = ((tday[:, None] < tx[..., 2])
-                 | ((tday[:, None] == tx[..., 2]) & (tms[:, None] <= tx[..., 3])))
-    temporal = jnp.any(after_lo & before_hi & time_valid[None, :], axis=1)
-    return spatial & temporal
+        hit = hit & (jnp.arange(xhi.shape[0]) < n_valid)
+    if not time_any:
+        tx = times[None, :, :]                  # (1, B, 4)
+        after_lo = ((tday[:, None] > tx[..., 0])
+                    | ((tday[:, None] == tx[..., 0])
+                       & (tms[:, None] >= tx[..., 1])))
+        before_hi = ((tday[:, None] < tx[..., 2])
+                     | ((tday[:, None] == tx[..., 2])
+                        & (tms[:, None] <= tx[..., 3])))
+        hit = hit & jnp.any(after_lo & before_hi & time_valid[None, :],
+                            axis=1)
+    if not flag_boundary:
+        return hit
+    cand = _cand_body(xhi, yhi, boxes, box_valid, n_valid)
+    return hit.astype(jnp.uint8) | (cand.astype(jnp.uint8) << 1)
 
 
-_scan_mask = functools.partial(jax.jit, static_argnames=("time_any",))(
-    _mask_body)
+_scan_mask = functools.partial(
+    jax.jit, static_argnames=("time_any", "flag_boundary"))(_mask_body)
 
 
 @functools.partial(jax.jit, static_argnames=("time_any",))
@@ -344,6 +352,30 @@ def scan_mask(data: DeviceScanData, q: ScanQuery) -> jax.Array:
                       q.time_any, n_valid)
 
 
+def scan_codes(data: DeviceScanData, q: ScanQuery) -> jax.Array:
+    """``scan_mask`` with the boundary flags: a device uint8[cap] code a
+    row, bit 0 the two-float verdict and bit 1 set where the row's
+    hi-cell equals a query bound's (``boundary_candidates`` on the
+    device). Capacity-padding rows read 0."""
+    n_valid = None if data.cap == data.n else data.n
+    return _scan_mask(data.xhi, data.xlo, data.yhi, data.ylo,
+                      data.tday, data.tms,
+                      q.boxes, q.box_valid, q.times, q.time_valid,
+                      q.time_any, n_valid, flag_boundary=True)
+
+
+_F32_TINY = np.finfo(np.float32).tiny
+
+
+def _on_cell(hi: np.ndarray, b: np.float32) -> np.ndarray:
+    """Rows whose hi-cell equals the bound's hi-cell ``b`` as the device
+    compares them: it flushes f32 subnormals to zero, so a bound in the
+    zero cell shares it with every subnormal hi."""
+    if abs(b) < _F32_TINY:
+        return np.abs(hi) < _F32_TINY
+    return hi == b
+
+
 def boundary_candidates(data_xhi: np.ndarray, data_yhi: np.ndarray,
                         q: ScanQuery) -> np.ndarray:
     """Host-side: indices of points whose hi-cell equals any query bound's
@@ -352,9 +384,45 @@ def boundary_candidates(data_xhi: np.ndarray, data_yhi: np.ndarray,
     mask = np.zeros(len(data_xhi), dtype=bool)
     for i in range(q.n_boxes):
         his = q.host_box_his[i]
-        mask |= (data_xhi == his[0]) | (data_xhi == his[1])
-        mask |= (data_yhi == his[2]) | (data_yhi == his[3])
+        mask |= _on_cell(data_xhi, his[0]) | _on_cell(data_xhi, his[1])
+        mask |= _on_cell(data_yhi, his[2]) | _on_cell(data_yhi, his[3])
     return np.flatnonzero(mask)
+
+
+_native_nonzero = None  # None = unprobed, False = unavailable
+
+
+def _nonzero_lib():
+    """ctypes handle to the native mask compaction (native/src/mask.cpp),
+    or None when the library does not load."""
+    global _native_nonzero
+    if _native_nonzero is None:
+        import ctypes
+        from ..native import symbols
+        lib = symbols({"geomesa_nonzero_u8": (
+            ctypes.c_int64, [ctypes.c_void_p, ctypes.c_int64,
+                             ctypes.c_void_p, ctypes.c_int64])})
+        _native_nonzero = lib if lib is not None else False
+    return _native_nonzero or None
+
+
+def hit_rows(mask: np.ndarray) -> np.ndarray:
+    """``np.flatnonzero`` of a 1-d bool or uint8 row mask: the sorted
+    int64 indices of its nonzero bytes, compacted natively without a
+    branch on the data where the library loads. numpy branches on each
+    byte, which costs 2-5x on a mask whose hits are scattered."""
+    lib = _nonzero_lib()
+    if lib is None or mask.ndim != 1 or mask.dtype not in (np.bool_,
+                                                           np.uint8):
+        return np.flatnonzero(mask)
+    mask = np.ascontiguousarray(mask)
+    cap = int(np.count_nonzero(mask)) + 1
+    out = np.empty(cap, dtype=np.int64)
+    k = lib.geomesa_nonzero_u8(mask.ctypes.data, len(mask),
+                               out.ctypes.data, cap)
+    if k < 0:
+        raise RuntimeError("native mask compaction overran its output")
+    return out[:k]
 
 
 def _exact_hits(cand_idx: np.ndarray, x: np.ndarray, y: np.ndarray,

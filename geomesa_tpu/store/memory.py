@@ -1667,66 +1667,78 @@ class InMemoryDataStore(DataStore):
             explain("Exact geometry predicate applied")
         return idx
 
-    def _patch_mask(self, st: _TypeState, mask, xhi, yhi, sel,
-                    sq: zscan.ScanQuery, explain: Explainer):
-        """Exact f64 recheck of rows whose hi-cell touches a query
-        bound; sel=None means full-table arrays, else a row subset
-        (rows outside a pruned candidate set are provably outside
-        the query in exact f64, so patching the subset is exact)."""
-        cand = zscan.boundary_candidates(xhi, yhi, sq)
-        tracer.current_span().set_attr(checked=int(len(cand)))
+    def _patch_mask(self, st: _TypeState, mask: np.ndarray,
+                    sq: zscan.ScanQuery, explain: Explainer) -> np.ndarray:
+        """Exact f64 recheck of the rows whose hi-cell touches a query
+        bound, written into the full-table mask of ``_dense_mask``: its
+        nonzero rows are then the exact hits. A uint8 code mask carries
+        the device's boundary flags in bit 1; a bool mask (Pallas) is
+        flagged by a host pass."""
+        if mask.dtype == np.uint8:
+            cand, source = np.flatnonzero(mask > 1), "device"
+        else:
+            cand = zscan.boundary_candidates(st.host_xhi, st.host_yhi, sq)
+            source = "host"
+        tracer.current_span().set_attr(checked=int(len(cand)),
+                                       source=source)
         if not len(cand):
             return mask
         batch = st.batch
         dtg = st.sft.dtg_field
         col = batch.col(st.sft.geom_field)
-        x, y = col.x, col.y
         millis = (batch.col(dtg).millis if dtg is not None
                   else np.zeros(st.n, dtype=np.int64))
-        if sel is not None:
-            x, y, millis = x[sel], y[sel], millis[sel]
         explain(f"Boundary recheck: {len(cand)} candidate(s)")
-        return zscan.exact_patch(mask, cand, x, y, millis, sq)
+        # the exact verdict overwrites the whole code: flagged rows
+        # become 0 or 1
+        return zscan.exact_patch(mask, cand, col.x, col.y, millis, sq)
+
+    def _patched_rows(self, st: _TypeState, mask: np.ndarray,
+                      sq: zscan.ScanQuery, explain: Explainer) -> np.ndarray:
+        """Both device tiers' last step: the exact patch of the dense
+        pass's mask and its sorted hit rows."""
+        with tracer.span("boundary-patch"):
+            return zscan.hit_rows(self._patch_mask(st, mask, sq, explain))
 
     @staticmethod
     def _dense_mask(st: _TypeState, sq: zscan.ScanQuery,
                     kernel: str) -> tuple[np.ndarray, int]:
         """The ``kernel`` ("xla" or "pallas") z3 pass over every row:
-        (host bool[n] two-float mask, rows scanned). One byte a scanned
-        row comes down."""
+        (host mask[n], rows scanned). One byte a scanned row comes down:
+        on the XLA path a uint8 code (bit 0 the two-float verdict, bit 1
+        the boundary flag), on the Pallas path the bool verdict."""
         if kernel == "pallas":
             from ..scan.pallas_scan import LANES, pallas_scan_mask
             data = st.pallas()
             return pallas_scan_mask(data, sq), int(data.rows * LANES)
-        mask = np.asarray(zscan.scan_mask(st.scan_data, sq))[:st.n]
-        return mask, int(st.scan_data.cap)
+        codes = np.asarray(zscan.scan_codes(st.scan_data, sq))[:st.n]
+        return codes, int(st.scan_data.cap)
 
     def _scan_gathered(self, st: _TypeState, sq: zscan.ScanQuery,
                        rows: np.ndarray, explain: Explainer,
                        nb: int, ni: int) -> np.ndarray:
-        """Index-pruned candidate tier: the dense z3 pass, its mask read
-        at the candidate rows on the host + boundary patch on the subset.
-        A row's verdict depends on its own values alone, so this is the
-        mask of a scan over just those rows, without a device gather of
-        random rows, which costs hundreds of dense passes."""
+        """Index-pruned candidate tier: the dense z3 pass + the
+        full-table boundary patch, as the dense tier. Rows outside the
+        index's candidates are outside the query in exact f64, so the
+        patched mask is the exact answer over the candidates too, with
+        no gather of random rows on the device (hundreds of dense passes)
+        or on the host."""
         explain(f"Index-pruned device scan: {len(rows)} candidate "
                 f"row(s) of {st.n}, {nb} box(es), {ni} interval(s)")
         m = len(rows)
         with tracer.span("gather-scan") as sp:
             t0 = time.perf_counter()
-            sub, scanned = np.zeros(0, dtype=bool), 0
+            mask, scanned = None, 0
             if m:
                 mask, scanned = self._dense_mask(st, sq, SCAN_KERNEL.get())
-                sub = mask[rows]
                 runtime.note_dispatch("scan", ("gathered", scanned),
                                       time.perf_counter() - t0,
                                       d2h_bytes=scanned)
             sp.set_attr(candidates=m, padded=scanned, h2d_bytes=0,
                         d2h_bytes=scanned)
-        with tracer.span("boundary-patch"):
-            sub = self._patch_mask(st, sub, st.host_xhi[rows],
-                                   st.host_yhi[rows], rows, sq, explain)
-            return np.sort(rows[sub])
+        if not m:
+            return np.zeros(0, dtype=np.int64)
+        return self._patched_rows(st, mask, sq, explain)
 
     def _scan_dense(self, st: _TypeState, sq: zscan.ScanQuery,
                     explain: Explainer, nb: int, ni: int) -> np.ndarray:
@@ -1743,10 +1755,7 @@ class InMemoryDataStore(DataStore):
             runtime.note_dispatch("scan", ("dense", padded),
                                   time.perf_counter() - t0,
                                   d2h_bytes=padded)
-        with tracer.span("boundary-patch"):
-            mask = self._patch_mask(st, mask, st.host_xhi, st.host_yhi,
-                                    None, sq, explain)
-            return np.flatnonzero(mask)
+        return self._patched_rows(st, mask, sq, explain)
 
     def _device_extent_scan(self, st: _TypeState, q: Query,
                             strategy: FilterStrategy,

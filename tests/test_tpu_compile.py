@@ -72,6 +72,18 @@ def test_dense_scan_100m(one_chip):
     assert c.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+def test_dense_codes_100m(one_chip):
+    # the code byte a row (verdict + boundary flag) in the same pass, as
+    # many bytes out as the bool mask
+    args = (*_scan_cols(one_chip, ROWS), *_query(one_chip, 4, 2))
+    c = _compile(zscan._scan_mask, *args, time_any=False,
+                 flag_boundary=True)
+    mask = _compile(zscan._scan_mask, *args, time_any=False)
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert (c.memory_analysis().output_size_in_bytes
+            == mask.memory_analysis().output_size_in_bytes)
+
+
 def test_batch_mask_10m_x32(one_chip):
     _compile(zscan._batch_mask, *_scan_cols(one_chip, 10_000_000),
              *_query(one_chip, 1, 1, (32,)), _s(one_chip, (), jnp.int32))
