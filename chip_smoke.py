@@ -10,8 +10,6 @@ answers:
   (a) the north-star BBOX+time query (exact host tier);
   (b) wide BBOX+time queries past ``geomesa.scan.host.rows`` (gathered
       and dense device tiers);
-  (c) the dense query as four boxes under ``geomesa.scan.kernel=pallas``
-      (the Pallas kernel, compiled by Mosaic on a TPU);
   (d) 32 concurrent BBOX queries through a QueryBatcher (fused kernel);
   (e) batched KNN, k=100, Q=8, through ``knn_process``;
   (f) ST_Contains counts over 1,024 polygons through ``contains_process``;
@@ -70,10 +68,6 @@ GATHERED = ("b_gathered", [(-100, -50, 100, 50)],
             ("2016-08-01", "2016-08-21"), "Index-pruned device scan")
 DENSE = ("b_dense", [(-160, -70, 160, 70)],
          ("2016-07-20", "2016-10-20"), "Device scan:")
-# the dense region as four closed quadrants: same rows, K = 4 boxes
-PALLAS = ("c_pallas", [(-160, -70, 0, 0), (0, -70, 160, 0),
-                       (-160, 0, 0, 70), (0, 0, 160, 70)],
-          DENSE[2], "Pallas device scan: 4 box(es)")
 
 
 def ecql(boxes, during) -> str:
@@ -168,32 +162,22 @@ def _timed(fn):
     return out, time.perf_counter() - t0
 
 
-def _query_phase(ds, data: Data, spec, kernel: str | None = None) -> dict:
+def _query_phase(ds, data: Data, spec) -> dict:
     _, boxes, during, tier = spec
-    from geomesa_tpu.store.memory import SCAN_KERNEL
     text = ecql(boxes, during)
     lines: list[str] = []
-    SCAN_KERNEL.set(kernel)
-    try:
-        first, first_s = _timed(
-            lambda: ds.query(text, TYPE, explain_out=lines.append))
-        warm, warm_s = _timed(lambda: ds.query(text, TYPE))
-    finally:
-        SCAN_KERNEL.set(None)
+    first, first_s = _timed(
+        lambda: ds.query(text, TYPE, explain_out=lines.append))
+    warm, warm_s = _timed(lambda: ds.query(text, TYPE))
     want = data.ids[data.box_rows(boxes, during)]
     taken = [ln.strip() for ln in lines
-             if any(t[3] in ln for t in (NORTH_STAR, GATHERED, DENSE,
-                                         PALLAS))]
-    out = {"hits": int(warm.n),
-           "exact": bool(np.array_equal(first.ids, want)
-                         and np.array_equal(warm.ids, want)),
-           "tier": taken[0] if taken else None,
-           "tier_ok": any(tier in ln for ln in taken),
-           "first_s": first_s, "wall_s": warm_s}
-    if kernel == "pallas":
-        from geomesa_tpu.scan import pallas_scan
-        out["pallas_interpret"] = pallas_scan._interpret()
-    return out
+             if any(t[3] in ln for t in (NORTH_STAR, GATHERED, DENSE))]
+    return {"hits": int(warm.n),
+            "exact": bool(np.array_equal(first.ids, want)
+                          and np.array_equal(warm.ids, want)),
+            "tier": taken[0] if taken else None,
+            "tier_ok": any(tier in ln for ln in taken),
+            "first_s": first_s, "wall_s": warm_s}
 
 
 def _batched_phase(ds, data: Data, seed: int) -> dict:
@@ -357,7 +341,7 @@ def _load(ds, data: Data) -> dict:
 
 
 def run_single(rows: int, seed: int, emit) -> list[dict]:
-    """Phases (a)-(g) on the first device."""
+    """Phases (a), (b) and (d)-(g) on the first device."""
     from geomesa_tpu.store import InMemoryDataStore
     comp = Compiles()
     data, gen_s = _timed(lambda: Data(rows, seed))
@@ -369,7 +353,6 @@ def run_single(rows: int, seed: int, emit) -> list[dict]:
         (GATHERED[0], lambda: _query_phase(ds, data, GATHERED)),
         (DENSE[0], lambda: _query_phase(ds, data, DENSE)),
         ("d_batched", lambda: _batched_phase(ds, data, seed)),
-        (PALLAS[0], lambda: _query_phase(ds, data, PALLAS, "pallas")),
         ("e_knn", lambda: _knn_phase(ds, data)),
         ("f_contains", lambda: _contains_phase(ds, data, seed)),
         ("g_web", lambda: _web_phase(ds, data)),

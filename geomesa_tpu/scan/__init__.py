@@ -7,13 +7,10 @@ one XLA program: predicate masks + optional aggregation, executed on the
 shard holding the data, with ICI collectives as the reduce.
 """
 
-from .pallas_scan import (PallasScanData, build_pallas_data,
-                          pallas_scan_count, pallas_scan_mask)
 from .zscan import (DeviceScanData, ScanQuery, boundary_candidates,
                     build_scan_data, exact_patch, make_query, scan_mask,
                     split_two_float)
 
 __all__ = ["DeviceScanData", "ScanQuery", "boundary_candidates",
            "build_scan_data", "exact_patch", "make_query", "scan_mask",
-           "split_two_float", "PallasScanData", "build_pallas_data",
-           "pallas_scan_count", "pallas_scan_mask"]
+           "split_two_float"]

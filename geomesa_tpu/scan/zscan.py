@@ -35,9 +35,8 @@ ensure_compile_cache()
 __all__ = ["BatchedScanQuery", "DeviceScanData", "ScanQuery",
            "batch_hit_rows", "build_scan_data", "extend_scan_data",
            "hit_rows", "make_query", "next_pow2", "patch_hit_rows",
-           "scan_codes", "scan_mask", "scan_mask_at", "scan_mask_batch",
-           "scan_mask_batch_at", "split_two_float", "stack_points",
-           "stack_queries", "MILLIS_PER_DAY"]
+           "scan_codes", "scan_mask", "scan_mask_at", "split_two_float",
+           "stack_points", "stack_queries", "MILLIS_PER_DAY"]
 
 MILLIS_PER_DAY = 86_400_000
 
@@ -490,16 +489,6 @@ class BatchedScanQuery:
     def n_queries(self) -> int:
         return len(self.queries)
 
-    @property
-    def padded_queries(self) -> int:
-        return int(self._np[0].shape[0])
-
-    @property
-    def shape_key(self) -> tuple[int, int, int]:
-        """(Qp, K, B) — the jit shape class of this batch."""
-        return (int(self._np[0].shape[0]), int(self._np[0].shape[1]),
-                int(self._np[2].shape[1]))
-
     def _device(self):
         if self._dev is None:
             self._dev = tuple(jnp.asarray(a) for a in self._np)
@@ -592,33 +581,12 @@ def _cand_body(xhi, yhi, boxes, box_valid, n_valid=None):
 
 
 @jax.jit
-def _batch_mask(xhi, xlo, yhi, ylo, tday, tms,
-                boxes, box_valid, times, time_valid, n_valid):
-    def one(bx, bv, tx, tv):
-        return _mask_body(xhi, xlo, yhi, ylo, tday, tms,
-                          bx, bv, tx, tv, time_any=False, n_valid=n_valid)
-    return jax.vmap(one)(boxes, box_valid, times, time_valid)
-
-
-@jax.jit
 def _batch_mask_cand(xhi, xlo, yhi, ylo, tday, tms,
                      boxes, box_valid, times, time_valid, n_valid):
     def one(bx, bv, tx, tv):
         return (_mask_body(xhi, xlo, yhi, ylo, tday, tms,
                            bx, bv, tx, tv, time_any=False, n_valid=n_valid),
                 _cand_body(xhi, yhi, bx, bv, n_valid))
-    return jax.vmap(one)(boxes, box_valid, times, time_valid)
-
-
-@jax.jit
-def _batch_gather_mask(xhi, xlo, yhi, ylo, tday, tms, idx,
-                       boxes, box_valid, times, time_valid):
-    def g(a):
-        return jnp.take(a, idx, mode="clip")
-
-    def one(bx, bv, tx, tv):
-        return _mask_body(g(xhi), g(xlo), g(yhi), g(ylo), g(tday), g(tms),
-                          bx, bv, tx, tv, time_any=False, n_valid=None)
     return jax.vmap(one)(boxes, box_valid, times, time_valid)
 
 
@@ -635,33 +603,6 @@ def _batch_nonzero(mask, size: int):
     def one(row):
         return jnp.nonzero(row, size=size, fill_value=row.shape[0])[0]
     return jax.lax.map(one, mask)
-
-
-def scan_mask_batch(data: DeviceScanData,
-                    bq: BatchedScanQuery) -> jax.Array:
-    """One fused launch over all queries: device bool[Qp, cap] mask.
-    ``n_valid`` is traced (not static) so appends within a capacity
-    class never recompile."""
-    return _batch_mask(data.xhi, data.xlo, data.yhi, data.ylo,
-                       data.tday, data.tms,
-                       bq.boxes, bq.box_valid, bq.times, bq.time_valid,
-                       jnp.int32(data.n))
-
-
-def scan_mask_batch_at(data: DeviceScanData, bq: BatchedScanQuery,
-                       rows: np.ndarray) -> np.ndarray:
-    """Fused batch scan over one SHARED candidate row set (the union of
-    the batch's index candidates); host bool[Qp, len(rows)]."""
-    m = len(rows)
-    if m == 0:
-        return np.zeros((bq.padded_queries, 0), dtype=bool)
-    k = next_pow2(m)
-    idx = np.zeros(k, dtype=rows.dtype)
-    idx[:m] = rows
-    out = _batch_gather_mask(
-        data.xhi, data.xlo, data.yhi, data.ylo, data.tday, data.tms,
-        jnp.asarray(idx), bq.boxes, bq.box_valid, bq.times, bq.time_valid)
-    return np.asarray(out)[:, :m]
 
 
 def batch_hit_rows(data: DeviceScanData, bq: BatchedScanQuery

@@ -47,13 +47,6 @@ from ..utils.threads import ThreadManagement
 # process-wide query reaper (ThreadManagement.scala's 5s sweep)
 _REAPER = ThreadManagement()
 
-# dense z3 kernel selection (the dense and candidate tiers both run
-# it): "xla" (default) or "pallas" — the
-# hand-tiled kernel (scan/pallas_scan.py) is numerically identical and
-# parity-tested; the flag mirrors the reference's pluggable iterator
-# stack selection (AccumuloIndexAdapter.scanConfig choosing iterators)
-SCAN_KERNEL = SystemProperty("geomesa.scan.kernel", "xla")
-
 # index-pruned candidate sets at or below this size evaluate exactly on
 # host in f64 (one vectorized pass over the gathered rows) instead of a
 # device round trip — per-query latency is then index-search +
@@ -275,14 +268,10 @@ class _TypeState:
         self._scan_thunk = None  # deferred device build (see scan_data)
         self.extent_data = None  # gscan.ExtentScanData for non-points
         self.zindex = None       # index.zkeys.ZKeyIndex for points
-        self._host_xhi: np.ndarray | None = None
-        self._host_yhi: np.ndarray | None = None
         # lazily-built sorted attribute indexes (AttributeIndex analog)
         self.attr_idx: dict[str, Any] = {}
         # lazy device uploads of attribute columns for residual kernels
         self.devcols = None  # scan.residual.DeviceColumns
-        # lazily-built tiled columns for the Pallas kernel (flag-gated)
-        self.pallas_data = None
         self.dirty = False
         # per-feature visibility expressions (None = world-readable);
         # has_vis avoids an O(n) object-array scan on every query.
@@ -334,26 +323,6 @@ class _TypeState:
                       else np.zeros(self._batch.n, dtype=np.int64))
             return zscan.build_scan_data(col.x, col.y, millis)
         return build
-
-    @property
-    def host_xhi(self) -> np.ndarray | None:
-        self._ensure_host_split()
-        return self._host_xhi
-
-    @property
-    def host_yhi(self) -> np.ndarray | None:
-        self._ensure_host_split()
-        return self._host_yhi
-
-    def _ensure_host_split(self):
-        """Two-float hi parts of the coordinates, built on first use by
-        the boundary-patch/device tiers (deferred like scan_data)."""
-        if (self._host_xhi is None and self._batch is not None
-                and self.sft.geom_field is not None):
-            col = self._batch.col(self.sft.geom_field)
-            if isinstance(col, PointColumn):
-                self._host_xhi, _ = zscan.split_two_float(col.x)
-                self._host_yhi, _ = zscan.split_two_float(col.y)
 
     @property
     def n(self) -> int:
@@ -438,7 +407,6 @@ class _TypeState:
         # merged indexes go stale per-column; rebuild those lazily
         self.attr_idx.clear()
         self.devcols = None
-        self.pallas_data = None
         # pessimistically dirty: if index maintenance below fails midway,
         # the next read must rebuild rather than scan a short index
         self.dirty = True
@@ -465,16 +433,12 @@ class _TypeState:
                              dmillis: np.ndarray) -> bool:
         """Append the delta to the device scan structures; False leaves
         the state dirty so the next read rebuilds from scratch."""
-        dxhi, dxlo = zscan.split_two_float(col.x)
-        dyhi, dylo = zscan.split_two_float(col.y)
         if self._scan_data is None and self._scan_thunk is not None:
-            # device build still deferred: extend the host split (when
-            # materialized) and re-defer over the merged batch
-            if self._host_xhi is not None:
-                self._host_xhi = np.concatenate([self._host_xhi, dxhi])
-                self._host_yhi = np.concatenate([self._host_yhi, dyhi])
+            # device build still deferred: re-defer over the merged batch
             self._scan_thunk = self._deferred_scan_build()
             return True
+        dxhi, dxlo = zscan.split_two_float(col.x)
+        dyhi, dylo = zscan.split_two_float(col.y)
         scan_data = zscan.extend_scan_data(
             self.scan_data, col.x, col.y, dmillis,
             xy_split=(dxhi, dxlo, dyhi, dylo))
@@ -490,9 +454,6 @@ class _TypeState:
                 cap=zscan.next_pow2(self._batch.n + 1))
         # all structures built: publish atomically
         self.scan_data = scan_data
-        if self._host_xhi is not None:
-            self._host_xhi = np.concatenate([self._host_xhi, dxhi])
-            self._host_yhi = np.concatenate([self._host_yhi, dyhi])
         return True
 
     def delete(self, ids: set[str]):
@@ -509,7 +470,6 @@ class _TypeState:
         self.vis = self.vis[keep]
         self.attr_idx.clear()
         self.devcols = None
-        self.pallas_data = None
         self.dirty = True
 
     def ensure_index(self):
@@ -566,11 +526,8 @@ class _TypeState:
         self.extent_data = None
 
     def _build_point_index(self, x, y, millis):
-        # DEFER both the host two-float split (only the boundary-patch
-        # pass reads the hi parts) and the device upload: a selective
-        # first query resolves on the host z-index and pays neither
-        self._host_xhi = None
-        self._host_yhi = None
+        # DEFER the device upload: a selective first query resolves on
+        # the host z-index and never pays it
         self._scan_data = None
         self._scan_thunk = self._deferred_scan_build()
 
@@ -601,24 +558,6 @@ class _TypeState:
             from ..scan.residual import DeviceColumns
             self.devcols = DeviceColumns(self.batch)
         return self.devcols
-
-    def pallas(self):
-        """Tiled device columns for the Pallas dense-scan kernel, built
-        on first use under the geomesa.scan.kernel=pallas flag.
-
-        Unlike scan_data, pallas tiles rebuild fully after a write burst
-        (no capacity-padded extend yet) — the flag targets read-heavy
-        scans; write-heavy workloads should stay on the XLA path."""
-        self.flush()
-        if self.pallas_data is None:
-            from ..scan.pallas_scan import build_pallas_data
-            geom = self.sft.geom_field
-            dtg = self.sft.dtg_field
-            col = self._batch.col(geom)
-            millis = (self._batch.col(dtg).millis if dtg is not None
-                      else np.zeros(self._batch.n, dtype=np.int64))
-            self.pallas_data = build_pallas_data(col.x, col.y, millis)
-        return self.pallas_data
 
 
 def _synchronized(fn):
@@ -1595,9 +1534,9 @@ class InMemoryDataStore(DataStore):
     def _device_scan(self, st: _TypeState, q: Query,
                      strategy: FilterStrategy, explain: Explainer,
                      art: "_PlanArtifacts | None" = None) -> np.ndarray:
-        """The hot path: z-range index pruning -> fused device kernel
-        (read at the candidate rows, or dense) + exact boundary patch +
-        non-envelope geometry residual. Returns sorted row indices."""
+        """The hot path: z-range index pruning -> the dense z3 pass +
+        exact boundary patch + non-envelope geometry residual. Returns
+        sorted row indices."""
         sft = st.sft
         batch = st.batch
         geom = sft.geom_field
@@ -1608,9 +1547,8 @@ class InMemoryDataStore(DataStore):
         # z-range pruning (Z3IndexKeySpace.getRanges analog): the host
         # fast path resolves selective queries EXACTLY inside the index
         # (sequential passes over sorted-order coordinate copies); wider
-        # candidate sets fall to the candidate tier (the dense kernel's
-        # mask read at the candidate rows, patched on them alone), and
-        # beyond the block threshold to the dense tier. One
+        # candidate sets fall to the candidate tier, and beyond the block
+        # threshold to the dense tier, which both run the dense pass. One
         # decomposition serves all tiers (zkeys.search_rows).
         from ..index.zkeys import SCAN_BLOCK_THRESHOLD, search_rows
         block_cap = int(float(SCAN_BLOCK_THRESHOLD.get()) * st.n)
@@ -1667,22 +1605,16 @@ class InMemoryDataStore(DataStore):
             explain("Exact geometry predicate applied")
         return idx
 
-    def _patch_mask(self, st: _TypeState, mask: np.ndarray,
+    def _patch_mask(self, st: _TypeState, codes: np.ndarray,
                     sq: zscan.ScanQuery, explain: Explainer) -> np.ndarray:
-        """Exact f64 recheck of the rows whose hi-cell touches a query
-        bound, written into the full-table mask of ``_dense_mask``: its
-        nonzero rows are then the exact hits. A uint8 code mask carries
-        the device's boundary flags in bit 1; a bool mask (Pallas) is
-        flagged by a host pass."""
-        if mask.dtype == np.uint8:
-            cand, source = np.flatnonzero(mask > 1), "device"
-        else:
-            cand = zscan.boundary_candidates(st.host_xhi, st.host_yhi, sq)
-            source = "host"
-        tracer.current_span().set_attr(checked=int(len(cand)),
-                                       source=source)
+        """Exact f64 recheck of the rows the dense pass flagged (bit 1 of
+        the codes of ``_dense_mask``: the row's hi-cell touches a query
+        bound), written into the full-table codes: their nonzero rows are
+        then the exact hits."""
+        cand = np.flatnonzero(codes > 1)
+        tracer.current_span().set_attr(checked=int(len(cand)))
         if not len(cand):
-            return mask
+            return codes
         batch = st.batch
         dtg = st.sft.dtg_field
         col = batch.col(st.sft.geom_field)
@@ -1691,71 +1623,68 @@ class InMemoryDataStore(DataStore):
         explain(f"Boundary recheck: {len(cand)} candidate(s)")
         # the exact verdict overwrites the whole code: flagged rows
         # become 0 or 1
-        return zscan.exact_patch(mask, cand, col.x, col.y, millis, sq)
+        return zscan.exact_patch(codes, cand, col.x, col.y, millis, sq)
 
-    def _patched_rows(self, st: _TypeState, mask: np.ndarray,
+    def _patched_rows(self, st: _TypeState, codes: np.ndarray,
                       sq: zscan.ScanQuery, explain: Explainer) -> np.ndarray:
-        """Both device tiers' last step: the exact patch of the dense
-        pass's mask and its sorted hit rows."""
+        """The exact patch of the dense pass's codes and its sorted hit
+        rows."""
         with tracer.span("boundary-patch"):
-            return zscan.hit_rows(self._patch_mask(st, mask, sq, explain))
+            return zscan.hit_rows(self._patch_mask(st, codes, sq, explain))
 
     @staticmethod
-    def _dense_mask(st: _TypeState, sq: zscan.ScanQuery,
-                    kernel: str) -> tuple[np.ndarray, int]:
-        """The ``kernel`` ("xla" or "pallas") z3 pass over every row:
-        (host mask[n], rows scanned). One byte a scanned row comes down:
-        on the XLA path a uint8 code (bit 0 the two-float verdict, bit 1
-        the boundary flag), on the Pallas path the bool verdict."""
-        if kernel == "pallas":
-            from ..scan.pallas_scan import LANES, pallas_scan_mask
-            data = st.pallas()
-            return pallas_scan_mask(data, sq), int(data.rows * LANES)
+    def _dense_mask(st: _TypeState,
+                    sq: zscan.ScanQuery) -> tuple[np.ndarray, int]:
+        """The z3 pass over every row: (host codes[n], rows scanned). One
+        uint8 code a scanned row comes down: bit 0 the two-float verdict,
+        bit 1 the boundary flag."""
         codes = np.asarray(zscan.scan_codes(st.scan_data, sq))[:st.n]
         return codes, int(st.scan_data.cap)
+
+    def _dense_tier(self, st: _TypeState, sq: zscan.ScanQuery,
+                    explain: Explainer, span: str, kind: str,
+                    attrs) -> np.ndarray:
+        """Both one-chip device tiers: the dense pass inside the tier's
+        ``span``, noted as a ``kind`` scan dispatch, the span's
+        ``attrs(rows scanned)``, then the patched hit rows."""
+        with tracer.span(span) as sp:
+            t0 = time.perf_counter()
+            codes, scanned = self._dense_mask(st, sq)
+            runtime.note_dispatch("scan", (kind, scanned),
+                                  time.perf_counter() - t0,
+                                  d2h_bytes=scanned)
+            sp.set_attr(**attrs(scanned))
+        return self._patched_rows(st, codes, sq, explain)
 
     def _scan_gathered(self, st: _TypeState, sq: zscan.ScanQuery,
                        rows: np.ndarray, explain: Explainer,
                        nb: int, ni: int) -> np.ndarray:
-        """Index-pruned candidate tier: the dense z3 pass + the
-        full-table boundary patch, as the dense tier. Rows outside the
-        index's candidates are outside the query in exact f64, so the
-        patched mask is the exact answer over the candidates too, with
-        no gather of random rows on the device (hundreds of dense passes)
-        or on the host."""
+        """Index-pruned candidate tier: the dense tier's pass and patch.
+        Rows outside the index's candidates are outside the query in
+        exact f64, so the patched codes are the exact answer over the
+        candidates too, with no gather of random rows on the device or
+        the host."""
         explain(f"Index-pruned device scan: {len(rows)} candidate "
                 f"row(s) of {st.n}, {nb} box(es), {ni} interval(s)")
         m = len(rows)
-        with tracer.span("gather-scan") as sp:
-            t0 = time.perf_counter()
-            mask, scanned = None, 0
-            if m:
-                mask, scanned = self._dense_mask(st, sq, SCAN_KERNEL.get())
-                runtime.note_dispatch("scan", ("gathered", scanned),
-                                      time.perf_counter() - t0,
-                                      d2h_bytes=scanned)
-            sp.set_attr(candidates=m, padded=scanned, h2d_bytes=0,
-                        d2h_bytes=scanned)
         if not m:
+            with tracer.span("gather-scan") as sp:
+                sp.set_attr(candidates=0, padded=0, h2d_bytes=0,
+                            d2h_bytes=0)
             return np.zeros(0, dtype=np.int64)
-        return self._patched_rows(st, mask, sq, explain)
+        return self._dense_tier(
+            st, sq, explain, "gather-scan", "gathered",
+            lambda k: dict(candidates=m, padded=k, h2d_bytes=0,
+                           d2h_bytes=k))
 
     def _scan_dense(self, st: _TypeState, sq: zscan.ScanQuery,
                     explain: Explainer, nb: int, ni: int) -> np.ndarray:
-        """Dense full-batch tier: the flag-selected XLA or Pallas
-        kernel + full-table boundary patch."""
-        with tracer.span("dense-scan") as sp:
-            t0 = time.perf_counter()
-            kernel = SCAN_KERNEL.get()
-            label = "Pallas device" if kernel == "pallas" else "Device"
-            explain(f"{label} scan: {nb} box(es), {ni} interval(s), "
-                    f"n={st.n}")
-            mask, padded = self._dense_mask(st, sq, kernel)
-            sp.set_attr(rows=int(st.n), d2h_bytes=padded)
-            runtime.note_dispatch("scan", ("dense", padded),
-                                  time.perf_counter() - t0,
-                                  d2h_bytes=padded)
-        return self._patched_rows(st, mask, sq, explain)
+        """Dense full-batch tier: the dense pass + full-table boundary
+        patch."""
+        explain(f"Device scan: {nb} box(es), {ni} interval(s), n={st.n}")
+        return self._dense_tier(
+            st, sq, explain, "dense-scan", "dense",
+            lambda k: dict(rows=int(st.n), d2h_bytes=k))
 
     def _device_extent_scan(self, st: _TypeState, q: Query,
                             strategy: FilterStrategy,

@@ -4,6 +4,9 @@ code paths without TPU hardware (SURVEY.md section 4 test strategy)."""
 
 import os
 
+import numpy as np
+import pytest
+
 # force CPU even on a machine with a TPU: tests must be deterministic and
 # exercise an 8-device mesh. The config flag is set too, after import and
 # before any backend initializes. (tests/test_tpu_compile.py compiles for a
@@ -95,3 +98,30 @@ def pytest_configure(config):
         "kill-point crash+resume sweep, mid-drop write conflicts, "
         "REST/CLI surfaces; select with -m evolve — the randomized "
         "kill-point soak is additionally marked slow)")
+
+
+@pytest.fixture(scope="session")
+def point_store():
+    """Factory: a store of type ``pts`` (``dtg:Date,*geom:Point``) over
+    ``x, y, t`` with ids ``p<row>``. With ``appended`` the rows come in
+    three writes, each followed by a whole-world query that builds the
+    device columns: the second write outgrows them and they are rebuilt
+    with power-of-two headroom, the third is appended into that headroom
+    in place (``zscan.extend_scan_data``). Later queries then run over
+    capacity-padded columns."""
+    from geomesa_tpu.features import parse_spec
+    from geomesa_tpu.store import InMemoryDataStore
+
+    def make(x, y, t, appended=False):
+        n = len(x)
+        ids = np.array([f"p{i}" for i in range(n)], dtype=object)
+        ds = InMemoryDataStore()
+        ds.create_schema(parse_spec("pts", "dtg:Date,*geom:Point:srid=4326"))
+        cuts = [0, n // 2, n * 7 // 8, n] if appended else [0, n]
+        for a, b in zip(cuts, cuts[1:]):
+            ds.write_dict("pts", ids[a:b],
+                          {"dtg": t[a:b], "geom": (x[a:b], y[a:b])})
+            if appended:
+                ds.query("BBOX(geom, -180, -90, 180, 90)", "pts")
+        return ds
+    return make

@@ -1,20 +1,19 @@
 """The dense z3 pass's code byte: bit 0 is the two-float verdict
 (``scan_mask``), bit 1 flags exactly the rows ``boundary_candidates`` finds
-on the host, and the store's device tiers answer from the patched codes
-with the ids of the f64 filter and of the row-subset patch they replace."""
+on the host, the patched codes hold the f64 hits and no padding row, and
+the store's device tiers answer from them with the ids of the f64 filter
+and of the row-subset patch they replace, over appended columns too."""
 
 import numpy as np
 import pytest
 
-from geomesa_tpu.features import parse_spec
 from geomesa_tpu.filters import evaluate, parse_ecql
 from geomesa_tpu.index.api import Query
 from geomesa_tpu.index.zkeys import SCAN_BLOCK_THRESHOLD
 from geomesa_tpu.obs import tracer
 from geomesa_tpu.obs.trace import TRACE_SAMPLE
 from geomesa_tpu.scan import zscan
-from geomesa_tpu.store import InMemoryDataStore
-from geomesa_tpu.store.memory import HOST_SCAN_ROWS, SCAN_KERNEL
+from geomesa_tpu.store.memory import HOST_SCAN_ROWS
 
 MS = lambda s: int(np.datetime64(s, "ms").astype(np.int64))
 
@@ -127,16 +126,64 @@ def test_subnormal_cells_share_the_zero_bound():
     np.testing.assert_array_equal(np.flatnonzero(mask), [2, 3, 5])
 
 
+# -- the patched codes against numpy f64 -------------------------------------
+
+DAY = 86_400_000
+PARITY = [
+    ([(-80.0, 30.0, -60.0, 45.0)], [(20 * DAY, 50 * DAY)]),
+    ([(-10.0, -10.0, 10.0, 10.0)], []),                      # no time
+    ([(-80.0, 30.0, -60.0, 45.0), (0.0, 0.0, 30.0, 20.0),
+      (100.0, -50.0, 140.0, -10.0)],                         # 3 boxes -> pad 4
+     [(0, 10 * DAY), (90 * DAY, 99 * DAY)]),
+    ([(-180.0, -90.0, 180.0, 90.0)], [(0, 100 * DAY)]),      # whole world
+]
+
+
+@pytest.fixture(scope="module")
+def world():
+    rng = np.random.default_rng(5)
+    n = 300_001  # capacity-padded to 2^19 rows
+    x = rng.uniform(-180, 180, n)
+    y = rng.uniform(-90, 90, n)
+    t = rng.integers(0, 100 * DAY, n).astype(np.int64)
+    return x, y, t, zscan.build_scan_data(x, y, t,
+                                          cap=zscan.next_pow2(n + 1))
+
+
+def _patched_hits(data, q, x, y, t):
+    """scan_codes -> exact_patch of the flagged rows -> hit_rows."""
+    codes = np.asarray(zscan.scan_codes(data, q))
+    codes = zscan.exact_patch(codes, np.flatnonzero(codes > 1), x, y, t, q)
+    return zscan.hit_rows(codes)
+
+
+@pytest.mark.parametrize("boxes,intervals", PARITY)
+def test_patched_codes_are_the_f64_hits(world, boxes, intervals):
+    x, y, t, data = world
+    want = np.zeros(len(x), dtype=bool)
+    for xmin, ymin, xmax, ymax in boxes:
+        want |= (x >= xmin) & (x <= xmax) & (y >= ymin) & (y <= ymax)
+    if intervals:
+        want &= np.any([(t >= lo) & (t <= hi) for lo, hi in intervals],
+                       axis=0)
+    got = _patched_hits(data, zscan.make_query(boxes, intervals), x, y, t)
+    np.testing.assert_array_equal(got, np.flatnonzero(want))
+
+
+def test_padding_rows_never_match(world):
+    # whole-world query: every real row matches, no padding row does
+    x, y, t, data = world
+    assert data.cap > len(x)
+    q = zscan.make_query([(-180.0, -90.0, 180.0, 90.0)], [])
+    np.testing.assert_array_equal(_patched_hits(data, q, x, y, t),
+                                  np.arange(len(x)))
+
+
 # -- the store's device tiers -----------------------------------------------
 
 @pytest.fixture(scope="module")
-def ds(pts):
-    x, y, t = pts
-    ds = InMemoryDataStore()
-    ds.create_schema(parse_spec("pts", "dtg:Date,*geom:Point:srid=4326"))
-    ds.write_dict("pts", [f"p{i}" for i in range(len(x))],
-                  {"dtg": t, "geom": (x, y)})
-    return ds
+def stores(pts, point_store):
+    return {a: point_store(*pts, appended=a) for a in (False, True)}
 
 
 @pytest.fixture
@@ -146,17 +193,22 @@ def knobs():
     try:
         yield
     finally:
-        for p in (HOST_SCAN_ROWS, SCAN_BLOCK_THRESHOLD, SCAN_KERNEL,
-                  TRACE_SAMPLE):
+        for p in (HOST_SCAN_ROWS, SCAN_BLOCK_THRESHOLD, TRACE_SAMPLE):
             p.set(None)
         tracer.clear()
+
+
+def _his(st):
+    """The two-float hi parts of the store's x and y columns."""
+    col = st.batch.col("geom")
+    return zscan.split_two_float(col.x)[0], zscan.split_two_float(col.y)[0]
 
 
 def _old_dense(st, sq):
     """The dense tier before the codes: scan_mask + the host flag pass."""
     col = st.batch.col("geom")
     mask = np.asarray(zscan.scan_mask(st.scan_data, sq))[:st.n]
-    cand = zscan.boundary_candidates(st.host_xhi, st.host_yhi, sq)
+    cand = zscan.boundary_candidates(*_his(st), sq)
     return np.flatnonzero(zscan.exact_patch(
         mask, cand, col.x, col.y, st.batch.col("dtg").millis, sq))
 
@@ -166,8 +218,8 @@ def _old_gathered(st, sq, rows):
     the patch on them alone."""
     col = st.batch.col("geom")
     sub = zscan.scan_mask_at(st.scan_data, sq, rows)
-    cand = zscan.boundary_candidates(st.host_xhi[rows], st.host_yhi[rows],
-                                     sq)
+    xhi, yhi = _his(st)
+    cand = zscan.boundary_candidates(xhi[rows], yhi[rows], sq)
     sub = zscan.exact_patch(sub, cand, col.x[rows], col.y[rows],
                             st.batch.col("dtg").millis[rows], sq)
     return np.sort(rows[sub])
@@ -176,16 +228,17 @@ def _old_gathered(st, sq, rows):
 TIER_KNOBS = {"gathered": ("10", "0.9"), "dense": ("10", "0.00001")}
 
 
-@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+@pytest.mark.parametrize("appended", [False, True],
+                         ids=["written", "appended"])
 @pytest.mark.parametrize("tier", sorted(TIER_KNOBS))
 @pytest.mark.parametrize("timed", [False, True], ids=["no-time", "time"])
 @pytest.mark.parametrize("nboxes", [1, 3])
-def test_device_tiers_answer_f64_ids(ds, knobs, monkeypatch, kernel, tier,
-                                     timed, nboxes):
+def test_device_tiers_answer_f64_ids(pts, stores, knobs, monkeypatch,
+                                     appended, tier, timed, nboxes):
+    ds = stores[appended]
     host, thr = TIER_KNOBS[tier]
     HOST_SCAN_ROWS.set(host)
     SCAN_BLOCK_THRESHOLD.set(thr)
-    SCAN_KERNEL.set(kernel)
     bbox = " OR ".join(f"BBOX(geom, {a}, {b}, {c}, {d})"
                        for a, b, c, d in BOXES[:nboxes])
     ecql = (f"({bbox}) AND dtg DURING {T0}Z/{T1}Z" if timed else bbox)
@@ -202,6 +255,8 @@ def test_device_tiers_answer_f64_ids(ds, knobs, monkeypatch, kernel, tier,
     with tracer.span("batcher-wait", "pts", root=True) as root:
         res = ds.query(Query("pts", ecql))
     ((st, sq, rows, idx),) = seen
+    assert st.n == len(pts[0])
+    assert (st.scan_data.cap > st.n) == appended
     want = np.flatnonzero(evaluate(parse_ecql(ecql), st.batch))
     assert len(want) > 0
     np.testing.assert_array_equal(idx, want)
@@ -211,9 +266,8 @@ def test_device_tiers_answer_f64_ids(ds, knobs, monkeypatch, kernel, tier,
     assert set(res.ids.astype(str)) == set(st.batch.ids[want].astype(str))
     (patch,) = [s["attrs"] for s in tracer.get(root.trace_id)
                 if s["kind"] == "boundary-patch"]
-    flags = zscan.boundary_candidates(st.host_xhi, st.host_yhi, sq)
+    flags = zscan.boundary_candidates(*_his(st), sq)
     assert patch["checked"] == len(flags) > 0
-    assert patch["source"] == ("host" if kernel == "pallas" else "device")
 
 
 # -- the compaction of the patched mask into rows ----------------------------

@@ -1,18 +1,16 @@
 """Candidate tier: the dense z3 pass patched over the whole table gives the
 same ids as a scan of just the candidate rows (``zscan.scan_mask_at`` and
 the patch on those rows) and as the f64 filter, with points on and one ulp
-beside every box edge and time bound, under both dense kernels."""
+beside every box edge and time bound, over written and appended columns."""
 
 import numpy as np
 import pytest
 
-from geomesa_tpu.features import parse_spec
 from geomesa_tpu.filters import evaluate, parse_ecql
 from geomesa_tpu.index.api import Query
 from geomesa_tpu.index.zkeys import SCAN_BLOCK_THRESHOLD
 from geomesa_tpu.scan import zscan
-from geomesa_tpu.store import InMemoryDataStore
-from geomesa_tpu.store.memory import HOST_SCAN_ROWS, SCAN_KERNEL
+from geomesa_tpu.store.memory import HOST_SCAN_ROWS
 
 MS = lambda s: int(np.datetime64(s, "ms").astype(np.int64))
 
@@ -47,16 +45,17 @@ def _edge_points(rng, boxes, lo, hi):
 def _row_scan(st, sq, rows):
     """A scan of just ``rows`` and the boundary patch on them alone."""
     sub = zscan.scan_mask_at(st.scan_data, sq, rows)
-    cand = zscan.boundary_candidates(st.host_xhi[rows], st.host_yhi[rows],
-                                     sq)
     col = st.batch.col("geom")
+    xhi, _ = zscan.split_two_float(col.x[rows])
+    yhi, _ = zscan.split_two_float(col.y[rows])
+    cand = zscan.boundary_candidates(xhi, yhi, sq)
     sub = zscan.exact_patch(sub, cand, col.x[rows], col.y[rows],
                             st.batch.col("dtg").millis[rows], sq)
     return np.sort(rows[sub])
 
 
 @pytest.fixture(scope="module")
-def ds():
+def stores(point_store):
     rng = np.random.default_rng(24)
     n = 20_000
     lo, hi = MS(T0[:-1]), MS(T1[:-1])
@@ -65,11 +64,8 @@ def ds():
     y = np.concatenate([rng.uniform(-90, 90, n), ey])
     t = np.concatenate([rng.integers(MS("2020-01-01"), MS("2020-05-01"), n),
                         et])
-    ds = InMemoryDataStore()
-    ds.create_schema(parse_spec("pts", "dtg:Date,*geom:Point:srid=4326"))
-    ds.write_dict("pts", [f"p{i}" for i in range(len(x))],
-                  {"dtg": t, "geom": (x, y)})
-    return ds
+    # appended, the edge points arrive in the last, in-place write
+    return {a: point_store(x, y, t, appended=a) for a in (False, True)}
 
 
 @pytest.fixture
@@ -79,17 +75,18 @@ def candidate_tier():
     try:
         yield
     finally:
-        for p in (HOST_SCAN_ROWS, SCAN_BLOCK_THRESHOLD, SCAN_KERNEL):
+        for p in (HOST_SCAN_ROWS, SCAN_BLOCK_THRESHOLD):
             p.set(None)
 
 
-@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+@pytest.mark.parametrize("appended", [False, True],
+                         ids=["written", "appended"])
 @pytest.mark.parametrize("timed", [False, True], ids=["no-time", "time"])
 @pytest.mark.parametrize("nboxes", [1, 2])
-def test_candidate_tier_matches_row_scan_and_f64(ds, candidate_tier,
-                                                 monkeypatch, kernel, timed,
+def test_candidate_tier_matches_row_scan_and_f64(stores, candidate_tier,
+                                                 monkeypatch, appended, timed,
                                                  nboxes):
-    SCAN_KERNEL.set(kernel)
+    ds = stores[appended]
     bbox = " OR ".join(f"BBOX(geom, {a}, {b}, {c}, {d})"
                        for a, b, c, d in BOXES[:nboxes])
     ecql = f"({bbox}) AND {WINDOW}" if timed else bbox
@@ -108,6 +105,7 @@ def test_candidate_tier_matches_row_scan_and_f64(ds, candidate_tier,
                for ln in lines), lines
     ((st, sq, rows, idx),) = seen
     assert len(rows) > 0
+    assert (st.scan_data.cap > st.n) == appended
     np.testing.assert_array_equal(idx, _row_scan(st, sq, rows))
     want = np.flatnonzero(evaluate(parse_ecql(ecql), st.batch))
     np.testing.assert_array_equal(idx, want)

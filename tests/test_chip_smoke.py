@@ -35,12 +35,12 @@ def test_single_chip_phases_exact(device_tiers):
     assert not _failed(results), _failed(results)
     by = {r["phase"]: r for r in results}
     assert set(by) == {"load", "a_northstar", "b_gathered", "b_dense",
-                       "d_batched", "c_pallas", "e_knn", "f_contains",
+                       "d_batched", "e_knn", "f_contains",
                        "g_web"}
     assert by["a_northstar"]["tier"].startswith("Index-pruned host scan")
     assert by["b_gathered"]["tier"].startswith("Index-pruned device scan")
     assert by["b_dense"]["tier"].startswith("Device scan")
-    assert by["c_pallas"]["hits"] == by["b_dense"]["hits"] > 0
+    assert by["b_dense"]["hits"] > 0
     assert by["d_batched"]["coalesced"] > 1
     assert by["d_batched"]["dispatch_failed"] == 0
 

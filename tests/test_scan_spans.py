@@ -18,7 +18,7 @@ from geomesa_tpu.obs import runtime, trace, tracer
 from geomesa_tpu.obs.trace import TRACE_SAMPLE, TRACE_SLOW_MS
 from geomesa_tpu.scan.batcher import QueryBatcher
 from geomesa_tpu.store import InMemoryDataStore
-from geomesa_tpu.store.memory import HOST_SCAN_ROWS, SCAN_KERNEL
+from geomesa_tpu.store.memory import HOST_SCAN_ROWS
 
 pytestmark = pytest.mark.obs
 
@@ -54,7 +54,7 @@ def sampled():
         yield tracer
     finally:
         for p in (TRACE_SAMPLE, TRACE_SLOW_MS, HOST_SCAN_ROWS,
-                  SCAN_BLOCK_THRESHOLD, SCAN_KERNEL):
+                  SCAN_BLOCK_THRESHOLD):
             p.set(None)
         tracer.clear()
 
@@ -77,10 +77,8 @@ TIERS = {
     "host": ("100000", "0.9", "BBOX(geom, -5, -5, 5, 5)"),
     "gathered": ("10", "0.9", "BBOX(geom, -100, -60, 100, 60)"),
     "dense": ("10", "0.0001", "BBOX(geom, -100, -60, 100, 60)"),
-    "pallas": ("10", "0.0001", "BBOX(geom, -100, -60, 100, 60)"),
 }
-KERNEL = {"host": None, "gathered": "gather-scan", "dense": "dense-scan",
-          "pallas": "dense-scan"}
+KERNEL = {"host": None, "gathered": "gather-scan", "dense": "dense-scan"}
 
 
 @pytest.mark.parametrize("tier", sorted(TIERS))
@@ -88,8 +86,6 @@ def test_each_tier_yields_its_steps(ds, sampled, tier):
     host, thr, box = TIERS[tier]
     HOST_SCAN_ROWS.set(host)
     SCAN_BLOCK_THRESHOLD.set(thr)
-    if tier == "pallas":
-        SCAN_KERNEL.set("pallas")
     res, spans = _traced(ds, f"{box} AND {WINDOW}")
     kids = _children(spans)
     want = {"plan", "index-search", "assemble"}
@@ -108,14 +104,12 @@ def test_each_tier_yields_its_steps(ds, sampled, tier):
         # the dense pass scans every row; its mask comes down, a byte a row
         assert g["padded"] >= 20_000 > g["candidates"]
         assert g["h2d_bytes"] == 0 and g["d2h_bytes"] == g["padded"]
-    if tier in ("dense", "pallas"):
+    if tier == "dense":
         d = kids["dense-scan"]["attrs"]
         assert d["rows"] == 20_000 and d["d2h_bytes"] >= 20_000
     if tier != "host":
         patch = kids["boundary-patch"]["attrs"]
-        assert patch["checked"] >= 0
-        # the XLA pass flags the boundary rows; Pallas leaves it to the host
-        assert patch["source"] == ("host" if tier == "pallas" else "device")
+        assert set(patch) == {"checked"} and patch["checked"] >= 0
 
 
 @pytest.mark.parametrize("tier", ["gathered", "dense"])

@@ -2,7 +2,7 @@
 
 Nothing runs: XLA's TPU compiler is handed shapes for a chip that is
 described, not attached, and refuses what the chip would refuse — more
-scoped VMEM than a Pallas kernel may use, more HBM than the chip has.
+scoped VMEM than a kernel may use, more HBM than the chip has.
 Interpret-mode tests cannot see either. The topology is described inside
 a fixture, never at import: only one process at a time may load libtpu,
 and every xdist worker imports this file.
@@ -16,7 +16,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from geomesa_tpu.analytics import join
-from geomesa_tpu.scan import pallas_scan, zscan
+from geomesa_tpu.scan import zscan
 
 ROWS = 100_000_000  # the per-chip north-star scale
 
@@ -40,12 +40,6 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-@pytest.fixture
-def mosaic(monkeypatch):
-    """Lower Pallas kernels for the chip, not the CPU interpreter."""
-    monkeypatch.setattr(pallas_scan, "_interpret", lambda: False)
-
-
 def _s(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -66,26 +60,35 @@ def _compile(fn, *args, **static):
     return fn.lower(*args, **static).compile()
 
 
-def test_dense_scan_100m(one_chip):
+# (boxes, intervals, time_any): one box to the most a query pads to
+QUERY_SHAPES = pytest.mark.parametrize(
+    "k,b,time_any", [(1, 1, False), (4, 2, False), (8, 8, False),
+                     (8, 1, True)])
+
+
+@QUERY_SHAPES
+def test_dense_scan_100m(one_chip, k, b, time_any):
     c = _compile(zscan._scan_mask, *_scan_cols(one_chip, ROWS),
-                 *_query(one_chip, 4, 2), time_any=False)
+                 *_query(one_chip, k, b), time_any=time_any)
     assert c.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
-def test_dense_codes_100m(one_chip):
+@QUERY_SHAPES
+def test_dense_codes_100m(one_chip, k, b, time_any):
     # the code byte a row (verdict + boundary flag) in the same pass, as
     # many bytes out as the bool mask
-    args = (*_scan_cols(one_chip, ROWS), *_query(one_chip, 4, 2))
-    c = _compile(zscan._scan_mask, *args, time_any=False,
+    args = (*_scan_cols(one_chip, ROWS), *_query(one_chip, k, b))
+    c = _compile(zscan._scan_mask, *args, time_any=time_any,
                  flag_boundary=True)
-    mask = _compile(zscan._scan_mask, *args, time_any=False)
+    mask = _compile(zscan._scan_mask, *args, time_any=time_any)
     assert c.memory_analysis().temp_size_in_bytes < 1 << 30
     assert (c.memory_analysis().output_size_in_bytes
             == mask.memory_analysis().output_size_in_bytes)
 
 
 def test_batch_mask_10m_x32(one_chip):
-    _compile(zscan._batch_mask, *_scan_cols(one_chip, 10_000_000),
+    # the batched path's kernel: verdict and boundary flags per query
+    _compile(zscan._batch_mask_cand, *_scan_cols(one_chip, 10_000_000),
              *_query(one_chip, 1, 1, (32,)), _s(one_chip, (), jnp.int32))
 
 
@@ -95,24 +98,6 @@ def test_batch_compaction_100m_x32(one_chip):
     c = _compile(zscan._batch_nonzero,
                  _s(one_chip, (32, ROWS), jnp.bool_), size=1 << 20)
     assert c.memory_analysis().temp_size_in_bytes < 1 << 30
-
-
-@pytest.mark.parametrize("kernel", ["mask", "count"])
-@pytest.mark.parametrize("k,b,time_any", [(1, 1, False), (4, 2, False),
-                                          (8, 8, False), (8, 1, True)])
-def test_pallas_scan_100m(one_chip, mosaic, kernel, k, b, time_any):
-    """k >= 4 boxes overflowed the 16 MiB scoped VMEM before the
-    in-kernel SUB_R loop."""
-    rows = -(-ROWS // pallas_scan.LANES)
-    rows = -(-rows // pallas_scan.BLOCK_R) * pallas_scan.BLOCK_R
-    fn = pallas_scan._mask_call if kernel == "mask" \
-        else pallas_scan._count_call
-    cols = ([_s(one_chip, (rows, pallas_scan.LANES), jnp.float32)] * 4
-            + [_s(one_chip, (rows, pallas_scan.LANES), jnp.int32)] * 2)
-    c = _compile(fn, *cols, _s(one_chip, (k, 8), jnp.float32),
-                 _s(one_chip, (b, 4), jnp.int32),
-                 k=k, b=b, time_any=time_any, rows=rows)
-    assert "tpu_custom_call" in c.as_text()
 
 
 def test_contains_counts_100m(one_chip):
