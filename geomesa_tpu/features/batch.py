@@ -292,18 +292,22 @@ def _column_for(spec_type: str, name: str, data) -> Column:
 class FeatureBatch:
     """A batch of features: ids + one column per schema attribute."""
 
-    def __init__(self, sft: SimpleFeatureType, ids: np.ndarray,
-                 columns: dict[str, Column]):
+    def __init__(self, sft: SimpleFeatureType, ids: np.ndarray | None,
+                 columns: dict[str, Column], n: int | None = None):
+        """``ids`` None (with the length ``n``) makes a projection that
+        holds no ids: see ``take``."""
         self.sft = sft
-        self.ids = np.asarray(ids, dtype=object)
+        self.ids = None if ids is None else np.asarray(ids, dtype=object)
         self.columns = columns
-        ns = {c.n for c in columns.values()} | {len(self.ids)}
-        if len(ns) > 1:
+        ns = {c.n for c in columns.values()}
+        ns.add(n if self.ids is None else len(self.ids))
+        if len(ns) > 1 or None in ns:
             raise ValueError(f"column length mismatch: {ns}")
+        self._n = ns.pop()
 
     @property
     def n(self) -> int:
-        return len(self.ids)
+        return self._n
 
     def __len__(self) -> int:
         return self.n
@@ -323,10 +327,18 @@ class FeatureBatch:
             columns[a.name] = _column_for(a.type.name, a.name, data[a.name])
         return cls(sft, np.asarray(ids, dtype=object), columns)
 
-    def take(self, idx) -> "FeatureBatch":
+    def take(self, idx, columns=None, ids: bool = True) -> "FeatureBatch":
+        """The rows ``idx``. ``columns`` (names) projects the gather onto
+        those columns, in schema order; a name the batch lacks is left
+        out, so reading it raises in ``col`` as it would on a full take.
+        ``ids=False`` gathers no ids (``ids`` is None, ``n`` still the
+        row count)."""
         idx = np.asarray(idx)
-        return FeatureBatch(self.sft, self.ids[idx],
-                            {k: c.take(idx) for k, c in self.columns.items()})
+        cols = (self.columns.items() if columns is None else
+                [(k, c) for k, c in self.columns.items() if k in columns])
+        n = int(np.count_nonzero(idx)) if idx.dtype == bool else len(idx)
+        return FeatureBatch(self.sft, self.ids[idx] if ids else None,
+                            {k: c.take(idx) for k, c in cols}, n=n)
 
     def feature(self, i: int) -> dict[str, Any]:
         """Row view (for result iteration / debugging)."""

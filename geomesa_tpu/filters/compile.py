@@ -38,7 +38,7 @@ import dataclasses
 import numpy as np
 
 from . import ast
-from .evaluate import evaluate
+from .evaluate import evaluate, take_for
 from .helper import (extract_attribute_bounds, extract_geometries,
                      extract_intervals, to_millis)
 
@@ -282,7 +282,7 @@ def exact_hits(cf: CompiledFilter, f: ast.Filter, batch,
     if cf.never or not len(candidates):
         return candidates[:0]
     if cf.residual:
-        keep = evaluate(f, batch.take(candidates))
+        keep = evaluate(f, take_for(f, batch, candidates))
     else:
         keep = exact_match(cf, batch, candidates)
     return candidates[keep]

@@ -26,12 +26,21 @@ from ..geometry import Envelope, Point
 from . import ast
 from .helper import dwithin_degrees, like_vocab_mask, to_millis
 
-__all__ = ["evaluate"]
+__all__ = ["evaluate", "take_for"]
 
 
 def evaluate(f: ast.Filter, batch: FeatureBatch) -> np.ndarray:
     """Evaluate filter over a batch; returns bool[n]."""
     return _eval(f, batch)
+
+
+def take_for(f: ast.Filter, batch: FeatureBatch, idx) -> FeatureBatch:
+    """The rows ``idx`` of ``batch`` holding only what ``f`` reads: the
+    columns it names, and the ids only under a ``FidFilter``. So
+    ``evaluate(f, take_for(f, batch, idx))`` equals
+    ``evaluate(f, batch.take(idx))`` at a fraction of the gather."""
+    fids = any(isinstance(node, ast.FidFilter) for node in ast.walk(f))
+    return batch.take(idx, columns=ast.props_of(f), ids=fids)
 
 
 def _eval(f: ast.Filter, b: FeatureBatch) -> np.ndarray:

@@ -32,7 +32,7 @@ from ..features.batch import FeatureBatch, PointColumn
 from ..features.sft import SimpleFeatureType, parse_spec
 from ..filters import ast
 from ..filters.ecql import parse_ecql
-from ..filters.evaluate import evaluate
+from ..filters.evaluate import evaluate, take_for
 from ..filters.helper import extract_geometries, extract_intervals
 from ..geometry import Envelope
 from ..index.api import Explainer, FilterStrategy, Query, QueryHints
@@ -1445,14 +1445,16 @@ class InMemoryDataStore(DataStore):
         with tracer.span("residual") as sp:
             if (len(idx) * 4 > st.n
                     and residual.is_compilable(residual_f, batch)):
-                sp.set_attr(where="device", rows=int(len(idx)), columns=0)
+                sp.set_attr(where="device", rows=int(len(idx)), columns=0,
+                            ids=False)
                 explain("Device residual scan (dense)")
                 mask = np.asarray(residual.device_mask(residual_f, batch,
                                                        st.device_cols()))
                 return idx[mask[idx]]
+            sub = take_for(residual_f, batch, idx)
             sp.set_attr(where="host", rows=int(len(idx)),
-                        columns=len(batch.columns))
-            return idx[evaluate(residual_f, batch.take(idx))]
+                        columns=len(sub.columns), ids=sub.ids is not None)
+            return idx[evaluate(residual_f, sub)]
 
     def _attr_scan(self, st: _TypeState, strategy: FilterStrategy,
                    explain: Explainer) -> np.ndarray:
@@ -1498,7 +1500,8 @@ class InMemoryDataStore(DataStore):
                 f"of {st.n}" + (" (date-narrowed)" if narrowed else ""))
         if not len(rows):
             return rows
-        keep = evaluate(strategy.primary, st.batch.take(rows))
+        keep = evaluate(strategy.primary,
+                        take_for(strategy.primary, st.batch, rows))
         return rows[keep]
 
     def _fill_artifacts(self, st: _TypeState, strategy: FilterStrategy,
@@ -1590,17 +1593,9 @@ class InMemoryDataStore(DataStore):
                 if spatial_f is not None:
                     col = batch.col(geom)
                     keep = self._pip_residual(spatial_f, col, idx, explain)
-                    if keep is None and isinstance(col, PointColumn) \
-                            and isinstance(spatial_f, (ast.Intersects,
-                                                       ast.Within)) \
-                            and hasattr(spatial_f.geom, "contains_points"):
-                        # host crossing-number on just the candidate
-                        # coords — a full batch.take gathers every
-                        # column for rows whose geometry alone decides
-                        keep = spatial_f.geom.contains_points(
-                            col.x[idx], col.y[idx]) & col.valid[idx]
                     if keep is None:
-                        keep = evaluate(spatial_f, batch.take(idx))
+                        keep = evaluate(spatial_f,
+                                        take_for(spatial_f, batch, idx))
                     idx = idx[keep]
             explain("Exact geometry predicate applied")
         return idx
@@ -1719,7 +1714,7 @@ class InMemoryDataStore(DataStore):
                     f"row(s) of {st.n}")
             if not len(rows):
                 return rows
-            keep = evaluate(primary, batch.take(rows))
+            keep = evaluate(primary, take_for(primary, batch, rows))
             return np.sort(rows[keep])
 
         eq = gscan.extent_query(boxes, intervals)
@@ -1737,7 +1732,7 @@ class InMemoryDataStore(DataStore):
         else:
             check = np.flatnonzero(state == 1)  # MAYBE band only
         if spatial_f is not None and len(check):
-            keep = evaluate(spatial_f, batch.take(check))
+            keep = evaluate(spatial_f, take_for(spatial_f, batch, check))
             if needs_exact:
                 mask = np.zeros(st.n, dtype=bool)
             mask = mask.copy()

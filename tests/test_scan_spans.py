@@ -130,17 +130,26 @@ def test_kernel_tiers_report_dispatches(ds, sampled, tier):
                 >= attrs.get(way, 0))
 
 
-@pytest.mark.parametrize("where, box", [
-    ("host", "BBOX(geom, -20, -20, 20, 20)"),
-    ("device", "BBOX(geom, -170, -80, 170, 80)"),
+@pytest.mark.parametrize("where, box, extra, columns, ids", [
+    pytest.param("host", "BBOX(geom, -20, -20, 20, 20)", "v < 30", 1,
+                 False, id="host-BBOX(geom, -20, -20, 20, 20)"),
+    pytest.param("host", "BBOX(geom, -20, -20, 20, 20)",
+                 "(v < 30 OR IN ('f1', 'f2'))", 1, True, id="host-fid"),
+    pytest.param("device", "BBOX(geom, -170, -80, 170, 80)", "v < 30", 0,
+                 False, id="device-BBOX(geom, -170, -80, 170, 80)"),
 ])
-def test_residual_reports_where_it_ran(ds, sampled, where, box):
+def test_residual_reports_where_it_ran(ds, sampled, where, box, extra,
+                                       columns, ids):
+    """``columns`` counts the columns the residual gathered: the host
+    branch gathers only those its filter names, and the ids only under
+    a FidFilter."""
     HOST_SCAN_ROWS.set("100000000")
-    res, spans = _traced(ds, f"{box} AND {WINDOW} AND v < 30")
+    res, spans = _traced(ds, f"{box} AND {WINDOW} AND {extra}")
     r = _children(spans)["residual"]["attrs"]
     assert r["where"] == where
     assert r["rows"] >= res.n > 0
-    assert r["columns"] == (4 if where == "host" else 0)
+    assert r["columns"] == columns
+    assert r["ids"] is ids
 
 
 def test_large_result_links_its_id_gather(sampled):
